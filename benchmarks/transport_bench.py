@@ -21,8 +21,9 @@ fewer collective-permute bytes than the dense replica gossip it replaces,
 and the packed elastic protocol moves fewer socket bytes than the dense
 contrib/gather exchange.
 
-The HLO/overlap plane runs in a subprocess (the bench process must keep the
-default 1-device config); the elastic plane spawns real worker processes.
+The HLO/overlap plane runs in a subprocess pinned to the CPU (8 fake host
+devices; the parent may already hold the accelerator, and a chip belongs to
+one process at a time); the elastic plane spawns real CPU worker processes.
 
 -> benchmarks/results/BENCH_transport.json
 """
@@ -190,6 +191,7 @@ def _elastic_rows(smoke: bool) -> list:
 def run(smoke: bool = False) -> list:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + env.get(
         "PYTHONPATH", "")
     out = subprocess.run(

@@ -61,12 +61,14 @@ def _dense_contract(w: jnp.ndarray, tree: PyTree) -> PyTree:
 
     Shared by ``dense_mix`` (W closed over) and ``scheduled_dense_mix`` (W_t
     traced from the round context) so both are the same arithmetic by
-    construction."""
+    construction.  Full f32 precision: the TPU's default for an f32 matmul
+    is one bf16 pass, which would round every gossiped parameter to bf16."""
 
     def one(x):
         xf = x.reshape(x.shape[0], -1)
         out = jnp.einsum(
-            "ij,jk->ik", w.astype(jnp.float32), xf.astype(jnp.float32)
+            "ij,jk->ik", w.astype(jnp.float32), xf.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST,
         )
         return out.reshape(x.shape).astype(x.dtype)
 
@@ -89,7 +91,10 @@ def allgather_mix(w: np.ndarray, axis_name: AxisName) -> MixFn:
 
         def one(x):
             full = lax.all_gather(x, axis_name, axis=0, tiled=False)  # (N, ...)
-            out = jnp.tensordot(row, full.astype(jnp.float32), axes=(0, 0))
+            out = jnp.tensordot(
+                row, full.astype(jnp.float32), axes=(0, 0),
+                precision=lax.Precision.HIGHEST,
+            )
             return out.astype(x.dtype)
 
         return jax.tree.map(one, tree)
@@ -307,16 +312,15 @@ def replicated_local(mesh) -> Callable[[Callable], Callable]:
     shard_map there is nothing to re-shard, so a collective-free body is
     guaranteed collective-free in the lowering; redundant decode compute
     is the (cheap, elementwise) price of wire-true link accounting."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     spec = PartitionSpec()
 
     def wrap(fn: Callable) -> Callable:
         def run(*trees: PyTree) -> PyTree:
-            return shard_map(
+            return jax.shard_map(
                 fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                check_rep=False,
+                check_vma=False,
             )(*trees)
 
         return run

@@ -13,8 +13,8 @@ Importing this package populates the registry:
     elementwise (tree_apply-able): mvr_update, axpby, add_sub,
                                    dse_combine, dse_combine_yh,
                                    qsgd_quantize, qsgd_dequantize
-    shaped:                        flash_attention, rms_norm, wkv_chunk,
-                                   top_k_pack, top_k_unpack
+    shaped:                        flash_attention, rms_norm, wkv_chunk
+    no kernel (XLA oracle):        top_k_pack, top_k_unpack
 """
 from . import api
 from . import (

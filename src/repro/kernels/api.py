@@ -13,14 +13,16 @@ never reached the hand-written kernels at all — it ran as per-leaf
     (wrapping the package's ``pl.pallas_call``), a :class:`TilePolicy`, and
     output-dtype rules.  ``register()`` wires the dispatch + custom VJP once.
   * platform dispatch — one mode resolver (``kernel`` on TPU, ``ref``
-    elsewhere; ``interpret`` force-able via :func:`dispatch_mode` or the
-    ``REPRO_FUSED_MODE`` env var) instead of four copy-pasted ``_on_tpu()``
-    helpers.  Every dispatch is differentiable: backward always runs the
-    jnp oracle through ``jax.vjp``.
+    elsewhere; ``interpret`` force-able via :func:`dispatch_mode`, or off the
+    TPU via the ``REPRO_FUSED_MODE`` env var, which a TPU refuses) instead
+    of four copy-pasted ``_on_tpu()`` helpers.  Every dispatch is
+    differentiable: backward always runs the jnp oracle through
+    ``jax.vjp``.
   * :func:`tree_apply` — the bucketed executor.  A whole parameter pytree is
     flattened into contiguous, lane-padded 1-D buffers (grouped by dtype
-    signature) so ONE kernel launch covers the entire tree instead of one
-    launch (or one XLA fusion) per leaf.  Padding to a lane multiple replaces
+    signature) so ONE kernel launch covers a tree of up to 64M elements
+    (larger trees: one per 64M-element group) instead of one launch (or one
+    XLA fusion) per leaf.  Padding to a lane multiple replaces
     the old ``while n % blk: blk //= 2`` halving loop that degraded
     odd-length buffers to tiny blocks or the ref fallback.
 
@@ -37,7 +39,7 @@ import functools
 import os
 import warnings
 from collections import Counter
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,24 +62,35 @@ MODES = ("kernel", "interpret", "ref")
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    """True when JAX's default device is a TPU.  A backend that fails to
+    start raises here: the program must not fall back to the CPU unseen."""
+    return jax.devices()[0].platform == "tpu"
 
 
-_mode_override: Optional[str] = (
+# CPU-side CI knob (e.g. ``REPRO_FUSED_MODE=interpret``); refused on a TPU
+_env_mode: Optional[str] = (
     os.environ.get("REPRO_FUSED_MODE", "").strip().lower() or None
 )
-if _mode_override is not None and _mode_override not in MODES:
-    raise ValueError(f"REPRO_FUSED_MODE={_mode_override!r} not in {MODES}")
+if _env_mode is not None and _env_mode not in MODES:
+    raise ValueError(f"REPRO_FUSED_MODE={_env_mode!r} not in {MODES}")
+
+_mode_override: Optional[str] = None   # set by dispatch_mode()
 
 
 def resolve_mode() -> str:
-    """Current dispatch mode: override if set, else kernel on TPU / ref off."""
+    """Current dispatch mode: a :func:`dispatch_mode` block if one is open,
+    else kernel on TPU / ``REPRO_FUSED_MODE`` or ref elsewhere."""
     if _mode_override is not None:
         return _mode_override
-    return "kernel" if on_tpu() else "ref"
+    if on_tpu():
+        if _env_mode is not None:
+            raise RuntimeError(
+                f"REPRO_FUSED_MODE={_env_mode!r} is set but the device is a "
+                "TPU, which always runs the kernels; unset it (tests force a "
+                "mode in code with api.dispatch_mode)"
+            )
+        return "kernel"
+    return _env_mode or "ref"
 
 
 @contextlib.contextmanager
@@ -140,11 +153,16 @@ class TilePolicy:
     ``n``.  The old halving loop turned an odd-length buffer into 1-element
     blocks and fell back to the oracle; padding wastes at most
     ``max_block - 1`` trailing elements and keeps every size on the kernel
-    path with full-width tiles.
+    path with full-width tiles.  The kernel sees a buffer as ``(n / lane,
+    lane)`` rows, tiled ``(block / lane, lane)``.
     """
 
     lane: int = LANE
     max_block: int = 1 << 16     # 64k elements/tile = 256 KB fp32
+    #: elements per launch (256 MB fp32).  Each launch copies its leaves into
+    #: contiguous buffers; unbounded, the fused DSE-MVR step at Gemma-2 2B
+    #: widths (4 layers) needed 15.9 GB of a v5e's 15.75, bounded 11.8 GB.
+    max_bucket: int = 1 << 26
 
     def plan(self, n: int) -> Tuple[int, int]:
         """(block, padded_n) for an ``n``-element flat buffer."""
@@ -153,13 +171,27 @@ class TilePolicy:
         block = self.max_block if n >= self.max_block else ceil_to(n, self.lane)
         return block, ceil_to(n, block)
 
+    def groups(self, sizes: Sequence[int]) -> List[List[int]]:
+        """Positions of ``sizes`` split into consecutive launch groups of at
+        most ``max_bucket`` elements (a larger leaf is a group alone)."""
+        out: List[List[int]] = [[]]
+        total = 0
+        for i, n in enumerate(sizes):
+            if out[-1] and total + n > self.max_bucket:
+                out.append([])
+                total = 0
+            out[-1].append(i)
+            total += n
+        return out
+
 
 # ---------------------------------------------------------------- the op
 @dataclasses.dataclass(frozen=True, eq=False)
 class FusedOp:
     """Declarative fused-op registration.
 
-    Exactly one of ``expr`` / ``kernel_fn`` is set:
+    At most one of ``expr`` / ``kernel_fn`` is set; with neither, the op
+    has no kernel and every platform and mode runs ``ref_fn`` through XLA:
 
     expr:       elementwise body ``expr(s, *ins) -> out | tuple`` where ``s``
                 indexes the packed fp32 scalar operands (``s[0]``, ...) and
@@ -191,8 +223,8 @@ class FusedOp:
     )
 
     def __post_init__(self):
-        if (self.expr is None) == (self.kernel_fn is None):
-            raise ValueError(f"{self.name}: exactly one of expr/kernel_fn")
+        if self.expr is not None and self.kernel_fn is not None:
+            raise ValueError(f"{self.name}: at most one of expr/kernel_fn")
         if self.expr is not None:
             if self.n_inputs <= 0:
                 raise ValueError(f"{self.name}: elementwise ops need n_inputs")
@@ -262,8 +294,11 @@ def _flat_launch(name, scalars, bufs, out_dtypes, block, interpret):
     that used to be duplicated per package)."""
     op = REGISTRY[name]
     (n,) = bufs[0].shape
-    assert n % block == 0, (name, n, block)
-    spec = lambda: pl.BlockSpec((block,), lambda i, *_: (i,))  # noqa: E731
+    assert n % block == 0 and block % LANE == 0, (name, n, block)
+    # (rows, lane) view: the TPU's native 2-D tiling; for a lane multiple it
+    # is the same bytes as the flat buffer
+    bufs = [b.reshape(n // LANE, LANE) for b in bufs]
+    spec = lambda: pl.BlockSpec((block // LANE, LANE), lambda i, *_: (i, 0))  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n // block,),
@@ -279,11 +314,11 @@ def _flat_launch(name, scalars, bufs, out_dtypes, block, interpret):
         _elementwise_kernel(op.expr, op.n_inputs, op.n_outputs),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.dtype(d)) for d in out_dtypes
+            jax.ShapeDtypeStruct((n // LANE, LANE), jnp.dtype(d)) for d in out_dtypes
         ],
         interpret=interpret,
     )(scal, *bufs)
-    return tuple(outs)
+    return tuple(o.reshape(n) for o in outs)
 
 
 def _flat_ref(op: FusedOp, scalars, bufs, out_dtypes):
@@ -337,7 +372,9 @@ def tree_apply(name: str, *trees: PyTree, scalars: Sequence = (), like=None):
     concatenated and padded to the op's tile policy — and dispatches the
     fused kernel ONCE per bucket, then splits the result back into the
     original tree.  A homogeneous-dtype parameter tree therefore costs
-    exactly one kernel launch per op per step, independent of leaf count.
+    one kernel launch per op per step, independent of leaf count, up to
+    ``TilePolicy.max_bucket`` elements; beyond that, one launch per group
+    of consecutive leaves of at most that size.
 
     scalars: traced/python scalar operands, delivered to the kernel via SMEM
     scalar-prefetch (one compiled kernel serves every schedule step).
@@ -394,34 +431,36 @@ def tree_apply(name: str, *trees: PyTree, scalars: Sequence = (), like=None):
         buckets.setdefault(key, []).append(i)
 
     out_leaves = [[None] * n_leaves for _ in range(op.n_outputs)]
-    for (_, out_dts), idxs in buckets.items():
-        sizes = [leaves[0][i].size for i in idxs]
-        n = sum(sizes)
-        if n == 0:   # bucket of empty leaves: nothing to launch
-            for i in idxs:
-                for j, d in enumerate(out_dts):
-                    out_leaves[j][i] = jnp.zeros(leaves[0][i].shape, jnp.dtype(d))
-            continue
-        block, n_pad = op.tile.plan(n)
+    for (_, out_dts), bucket in buckets.items():
+        for group in op.tile.groups([leaves[0][i].size for i in bucket]):
+            idxs = [bucket[g] for g in group]
+            sizes = [leaves[0][i].size for i in idxs]
+            n = sum(sizes)
+            if n == 0:   # group of empty leaves: nothing to launch
+                for i in idxs:
+                    for j, d in enumerate(out_dts):
+                        out_leaves[j][i] = jnp.zeros(leaves[0][i].shape, jnp.dtype(d))
+                continue
+            block, n_pad = op.tile.plan(n)
 
-        def cat(t):
-            parts = [leaves[t][i].ravel() for i in idxs]
-            buf = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-            return jnp.pad(buf, (0, n_pad - n)) if n_pad != n else buf
+            def cat(t):
+                parts = [leaves[t][i].ravel() for i in idxs]
+                buf = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+                return jnp.pad(buf, (0, n_pad - n)) if n_pad != n else buf
 
-        bufs = tuple(cat(t) for t in range(op.n_inputs))
-        _count(name, mode)
-        # named scope: one profiler-visible "repro/fused/<op>" region per
-        # dtype-bucket launch (HLO metadata only; numerics untouched)
-        with jax.named_scope(f"repro/fused/{name}"):
-            outs = _flat_fn(op, out_dts, block, mode)(scalars, bufs)
-        off = 0
-        for i, sz in zip(idxs, sizes):
-            for j in range(op.n_outputs):
-                out_leaves[j][i] = outs[j][off : off + sz].reshape(
-                    leaves[0][i].shape
-                )
-            off += sz
+            bufs = tuple(cat(t) for t in range(op.n_inputs))
+            _count(name, mode)
+            # named scope: one profiler-visible "repro/fused/<op>" region per
+            # launch (HLO metadata only; numerics untouched)
+            with jax.named_scope(f"repro/fused/{name}"):
+                outs = _flat_fn(op, out_dts, block, mode)(scalars, bufs)
+            off = 0
+            for i, sz in zip(idxs, sizes):
+                for j in range(op.n_outputs):
+                    out_leaves[j][i] = outs[j][off : off + sz].reshape(
+                        leaves[0][i].shape
+                    )
+                off += sz
 
     res = tuple(
         jax.tree.unflatten(treedef, out_leaves[j]) for j in range(op.n_outputs)
@@ -436,7 +475,8 @@ def call(name: str, *tensors, **static):
     Shaped ops: ``call("flash_attention", q, k, v, causal=True, ...)`` —
     keyword arguments are the op's static config (hashable).  Elementwise
     ops delegate to :func:`tree_apply` (``scalars=`` keyword carries the
-    scalar operands), so single arrays work too.
+    scalar operands), so single arrays work too.  Ops registered without a
+    kernel always run their oracle.
 
     Always differentiable: the backward pass is ``jax.vjp`` of ``ref_fn``.
     """
@@ -445,7 +485,7 @@ def call(name: str, *tensors, **static):
         return tree_apply(
             name, *tensors, scalars=static.pop("scalars", ()), **static
         )
-    mode = resolve_mode()
+    mode = resolve_mode() if op.kernel_fn is not None else "ref"
     key = ("shaped", tuple(sorted(static.items())), mode)
     fn = op._cache.get(key)
     if fn is None:

@@ -7,28 +7,14 @@ Elementwise exprs (compiled through the shared flat launcher,
                           buffer: ``sign(x) * min(floor(|x| * L + u), L)``.
   * ``qsgd_dequantize`` — ``q * scale / L`` (scale broadcast per node).
 
-Shaped launchers (packed sparsification payloads):
-
-  * ``top_k_pack``   — gather ``vals[i, j] = x[i, idx[i, j]]`` as a blocked
-                       one-hot contraction on the MXU (no dynamic scalar
-                       loads, scatter-free).
-  * ``top_k_unpack`` — scatter ``out[i, idx[i, j]] += vals[i, j]``, same
-                       one-hot trick per output block.
+The top-k pack/unpack ops have no kernel (see ``ops.py``): they run their
+XLA gather/scatter oracle on every platform.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-__all__ = [
-    "qsgd_quantize_expr", "qsgd_dequantize_expr",
-    "top_k_pack_fwd", "top_k_unpack_fwd", "DEFAULT_BLOCK",
-]
-
-DEFAULT_BLOCK = 512   # lane-aligned d-blocks for the pack/unpack one-hots
+__all__ = ["qsgd_quantize_expr", "qsgd_dequantize_expr"]
 
 
 # ------------------------------------------------------------- elementwise
@@ -43,75 +29,3 @@ def qsgd_dequantize_expr(s, q, scale):
     """q * scale / levels; scalars s = (1/levels,).  scale is the per-node
     max-|x| broadcast to the buffer shape."""
     return q * scale * s[0]
-
-
-
-# ---------------------------------------------------------------- pack
-def _pack_kernel(x_ref, idx_ref, out_ref, *, block):
-    j = pl.program_id(1)
-    x = x_ref[...].astype(jnp.float32)                    # (1, block)
-    idx = idx_ref[...]                                    # (1, k)
-    base = j * block
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1) + base
-    onehot = (idx[0][:, None] == iota[0][None, :]).astype(jnp.float32)  # (k, block)
-    part = jnp.dot(onehot, x[0][:, None])[:, 0]           # (k,)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
-
-    out_ref[...] += part[None, :].astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def top_k_pack_fwd(x, idx, *, block: int = DEFAULT_BLOCK, interpret: bool = False):
-    """vals[i, j] = x[i, idx[i, j]] for node-stacked x (N, d), idx (N, k)."""
-    n, d = x.shape
-    k = idx.shape[1]
-    d_pad = -(-d // block) * block
-    if d_pad != d:
-        x = jnp.pad(x, ((0, 0), (0, d_pad - d)))
-    out = pl.pallas_call(
-        functools.partial(_pack_kernel, block=block),
-        grid=(n, d_pad // block),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i, j: (i, j)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, k), jnp.float32),
-        interpret=interpret,
-    )(x, idx)
-    return out.astype(x.dtype)
-
-
-# ---------------------------------------------------------------- unpack
-def _unpack_kernel(idx_ref, val_ref, out_ref, *, block):
-    j = pl.program_id(1)
-    idx = idx_ref[...]                                    # (1, k)
-    val = val_ref[...].astype(jnp.float32)                # (1, k)
-    base = j * block
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1) + base
-    onehot = (idx[0][:, None] == iota[0][None, :]).astype(jnp.float32)  # (k, block)
-    out = jnp.dot(val, onehot)                            # (1, block)
-    out_ref[...] = out.astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("d", "block", "interpret"))
-def top_k_unpack_fwd(idx, vals, *, d: int, block: int = DEFAULT_BLOCK,
-                     interpret: bool = False):
-    """Dense (N, d) with out[i, idx[i, j]] += vals[i, j], zeros elsewhere."""
-    n, k = idx.shape
-    d_pad = -(-d // block) * block
-    out = pl.pallas_call(
-        functools.partial(_unpack_kernel, block=block),
-        grid=(n, d_pad // block),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, d_pad), jnp.float32),
-        interpret=interpret,
-    )(idx, vals)
-    return out[:, :d].astype(vals.dtype)

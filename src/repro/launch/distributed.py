@@ -95,18 +95,25 @@ class TrainJob:
     abstract_batch_fn: Callable       # (seq_len, global_batch) -> batch SDS tree
     scenario: Any = None
 
-    def lower(self, seq_len: int, global_batch: int):
-        batches = self.abstract_batch_fn(seq_len, global_batch)
-        args = (self.abstract_state, batches)
+    def jit_step(self):
+        """``step_fn`` jitted onto the mesh.  The state is donated: the new
+        state reuses the old one's buffers, so a round holds one copy of it
+        (callers must not read a state after passing it in)."""
         in_shardings = (self.state_shardings, self.batch_shardings)
         if self.scenario is not None:
-            args = args + (self.abstract_ctx(),)
             in_shardings = in_shardings + (None,)
         return jax.jit(
             self.step_fn,
             in_shardings=in_shardings,
             out_shardings=(self.state_shardings, None),
-        ).lower(*args)
+            donate_argnums=0,
+        )
+
+    def lower(self, seq_len: int, global_batch: int):
+        args = (self.abstract_state, self.abstract_batch_fn(seq_len, global_batch))
+        if self.scenario is not None:
+            args = args + (self.abstract_ctx(),)
+        return self.jit_step().lower(*args)
 
     # ---- scenario plumbing ------------------------------------------------
     def schedule_for(self, n_rounds: int):
@@ -149,17 +156,23 @@ class TrainJob:
         )
 
     def init_state(self, key) -> PyTree:
-        """Materialized initial state (small models / tests); attaches the
+        """Materialized initial state, built under jit straight into
+        ``state_shardings`` (each device computes only its own shard, so no
+        node's state passes through one device first); attaches the
         gossip-compression side state when the algorithm's spec asks for it."""
-        params = self.model.init(key)
-        n = self.n_nodes
-        stacked = jax.tree.map(
-            lambda p: jnp.broadcast_to(p[None], (n,) + p.shape), params
-        )
-        state = self.algorithm.init(stacked)
-        return attach_channel_state(
-            self.algorithm, state, jax.random.fold_in(key, 0x636F)
-        )
+
+        def build(key):
+            params = self.model.init(key)
+            n = self.n_nodes
+            stacked = jax.tree.map(
+                lambda p: jnp.broadcast_to(p[None], (n,) + p.shape), params
+            )
+            state = self.algorithm.init(stacked)
+            return attach_channel_state(
+                self.algorithm, state, jax.random.fold_in(key, 0x636F)
+            )
+
+        return jax.jit(build, out_shardings=self.state_shardings)(key)
 
 
 def _node_batch_struct(model: Model, tau: int, n_nodes: int, seq_len: int, global_batch: int):

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.distributed import make_serve_job
 from repro.launch.train import make_mesh_for_devices
 from repro.models import Model
@@ -37,6 +38,7 @@ def main(argv=None):
     p.add_argument("--temperature", type=float, default=0.0, help="0 = greedy")
     args = p.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if cfg.head != "lm":
         raise SystemExit(f"{cfg.name} is encoder-only: no decode path")

@@ -21,20 +21,23 @@ joining an external coordinator (the multi-host path: one command per box).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import time
+from typing import Any, Dict, List
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_reduced
 from repro.core import ALGORITHMS
 from repro.data import TokenPipeline, make_lm_tokens
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.distributed import make_train_job
 from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.models import ModelConfig
 
 
 def make_mesh_for_devices():
@@ -101,7 +104,8 @@ def _main_elastic(args):
     return res
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The train CLI's arguments (also parsed by ``chip_smoke.py``)."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="yi-9b")
     p.add_argument("--reduced", action="store_true", help="use the smoke-scale config")
@@ -157,17 +161,27 @@ def main(argv=None):
     p.add_argument("--jax-distributed", action="store_true",
                    help="elastic mode: jax.distributed.initialize the group "
                         "(fixed membership — no kill/rejoin chaos)")
-    args = p.parse_args(argv)
+    return p
 
-    if args.coordinator:
-        from repro.runtime.worker import run_worker
 
-        return run_worker(args.coordinator, args.process_id)
-    if args.num_processes:
-        return _main_elastic(args)
+@dataclasses.dataclass
+class TrainRun:
+    """What :func:`train` leaves behind: the final state, the per-round
+    history, the step's compile seconds and each round's seconds (host
+    clock, up to the loss reaching the host)."""
 
-    cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
-    mesh = make_mesh_for_devices()
+    state: Any
+    history: List[Dict[str, float]]
+    compile_s: float
+    round_s: List[float]
+
+
+def train(cfg: ModelConfig, args: argparse.Namespace, mesh=None) -> TrainRun:
+    """The single-process sharded training loop behind the CLI: build the
+    job on ``mesh`` (default: :func:`make_mesh_for_devices`), shard the
+    initial state, compile one round ahead of time and run ``args.steps``
+    rounds of ``args`` (a :func:`build_parser` namespace)."""
+    mesh = mesh if mesh is not None else make_mesh_for_devices()
     print(f"[train] arch={cfg.name} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
     job = make_train_job(
@@ -189,11 +203,6 @@ def main(argv=None):
     pipe = TokenPipeline(tokens, args.seq_len, args.global_batch, seed=args.seed)
 
     state = job.init_state(jax.random.key(args.seed))
-    step = jax.jit(
-        job.step_fn,
-        in_shardings=(job.state_shardings, job.batch_shardings),
-        out_shardings=(job.state_shardings, None),
-    )
 
     def round_batches():
         xs, ys = [], []
@@ -201,10 +210,16 @@ def main(argv=None):
             x, y = pipe.batch()
             xs.append(x.reshape(n, args.global_batch // n, args.seq_len))
             ys.append(y.reshape(n, args.global_batch // n, args.seq_len))
-        return {
-            "tokens": jnp.asarray(np.stack(xs)),
-            "targets": jnp.asarray(np.stack(ys)),
-        }
+        return jax.device_put(
+            {"tokens": np.stack(xs), "targets": np.stack(ys)},
+            job.batch_shardings,
+        )
+
+    batches = round_batches()
+    t_c = time.perf_counter()
+    step = job.jit_step().lower(state, batches).compile()
+    compile_s = time.perf_counter() - t_c
+    print(f"[train] step compiled in {compile_s:.1f}s")
 
     ckpt = CheckpointManager(os.path.join(args.out, "ckpt")) if args.out and args.ckpt_every else None
 
@@ -219,13 +234,18 @@ def main(argv=None):
     from repro.telemetry.spans import profile_trace, span
 
     history = []
+    round_s = []
     t0 = time.time()
     with profile_trace(args.profile):
         for r in range(args.steps):
+            t_r = time.perf_counter()
+            if r:
+                batches = round_batches()
             with span(tel, "round", step=r) as sp:
-                state, metrics = step(state, round_batches())
+                state, metrics = step(state, batches)
                 sp.fence((state, metrics))
             loss = float(metrics["loss"])
+            round_s.append(time.perf_counter() - t_r)
             if tel is not None:
                 tel.gauge("train_loss", loss, step=r + 1)
                 tel.record_link_bytes(link, step=r)
@@ -244,7 +264,22 @@ def main(argv=None):
         with open(os.path.join(args.out, "history.json"), "w") as f:
             json.dump(history, f, indent=1)
     print(f"[train] done: loss {history[0]['loss']:.4f} -> {history[-1]['loss']:.4f}")
-    return history
+    return TrainRun(state, history, compile_s, round_s)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
+    if args.coordinator:
+        from repro.runtime.worker import run_worker
+
+        return run_worker(args.coordinator, args.process_id)
+    if args.num_processes:
+        return _main_elastic(args)
+
+    use_compile_cache()
+    cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
+    return train(cfg, args).history
 
 
 if __name__ == "__main__":

@@ -7,6 +7,12 @@
 fan-out; the chaos plan kills/pauses/respawns them mid-run through the
 :class:`~repro.runtime.chaos.ChaosController` so faults exercise the actual
 sockets, signals and resync paths rather than simulated masks.
+
+The elastic runtime is a CPU plane: each worker is started with
+``JAX_PLATFORMS=cpu`` (unless ``env_overrides`` says otherwise) and fans
+out ``config.host_devices`` fake XLA host devices.  A chip belongs to one
+process at a time, so workers are given no accelerator until there is one
+chip per worker to give them.
 """
 from __future__ import annotations
 
@@ -122,7 +128,9 @@ def launch(
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={config.host_devices}"
         )
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # CPU-only workers (see the module docstring): a worker that reached
+        # for the chip would fail or hang while another process holds it
+        env["JAX_PLATFORMS"] = "cpu"
         env.update(env_overrides or {})
         log = open(os.path.join(log_dir, f"worker_{worker_id}.log"), "ab")
         return subprocess.Popen(

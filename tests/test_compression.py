@@ -357,7 +357,9 @@ def test_compression_fused_ops_registered():
 
     names = {"qsgd_quantize", "qsgd_dequantize", "top_k_pack", "top_k_unpack"}
     assert names <= set(api.REGISTRY)
-    assert api.REGISTRY["top_k_pack"].kernel_fn is not None
+    # top-k has no kernel: its one-hot design could not compile at real k
+    assert api.REGISTRY["top_k_pack"].kernel_fn is None
+    assert api.REGISTRY["top_k_unpack"].kernel_fn is None
     assert api.REGISTRY["qsgd_quantize"].expr is not None
 
 

@@ -242,10 +242,7 @@ def test_dryrun_hlo_analysis_sane():
         job = make_train_job(cfg, mesh, tau=3)
         compiled = job.lower(seq_len=128, global_batch=8).compile()
         ours = analyze_module(compiled.as_text())
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per computation
-            ca = ca[0]
-        xla = ca["flops"]
+        xla = compiled.cost_analysis()["flops"]
         assert ours.flops >= xla, (ours.flops, xla)
         print("ANALYSIS OK", ours.flops, xla)
     """)

@@ -169,11 +169,19 @@ def test_tile_policy_pads_to_lane_multiple():
     assert block == 1 << 16 and n_pad == 2 << 16
 
 
-def test_mvr_update_odd_buffer_stays_on_kernel_path():
+def test_tile_policy_groups_bound_each_launch():
+    tp = api.TilePolicy(max_bucket=100)
+    assert tp.groups([30, 30, 30]) == [[0, 1, 2]]
+    assert tp.groups([60, 50, 10, 250, 5]) == [[0], [1, 2], [3], [4]]
+    assert tp.groups([0, 0]) == [[0, 1]]
+
+
+@pytest.mark.parametrize("n", [12345, 2 * (1 << 16) + 384])
+def test_mvr_update_odd_buffer_stays_on_kernel_path(n):
     """Regression (block-selection satellite): an odd-length buffer used to
     degrade to 1-element blocks and the oracle fallback; now it is padded to
-    a lane multiple and takes ONE kernel launch."""
-    n = 12345  # odd, not lane-aligned
+    a lane multiple and takes ONE kernel launch.  The second size spans
+    several full blocks and a padded last one."""
     ks = jax.random.split(jax.random.key(n), 3)
     gn, v, go = (jax.random.normal(k, (n,)) for k in ks)
     api.reset_counters()

@@ -36,10 +36,7 @@ def test_scan_trip_count_exact():
     )
     ours = analyze_module(compiled.as_text())
     assert ours.flops == 13 * 2 * 8 * 8 * 8
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per computation
-        ca = ca[0]
-    assert ca["flops"] < ours.flops / 6  # the undercount we correct
+    assert compiled.cost_analysis()["flops"] < ours.flops / 6  # the undercount we correct
 
 
 def test_nested_scan_multiplies():
