@@ -50,6 +50,11 @@ __all__ = [
     "reset_legacy_warnings",
 ]
 
+#: named scope of the gossip mix (collectives, neighbour combination and any
+#: channel's codec work) around each ``mix_fn`` call the executor hands to
+#: ``comm_update``
+MIX_SCOPE = "repro/mix"
+
 CADENCES = ("every_step", "every_tau")
 RESETS = ("none", "minibatch", "full")
 
@@ -466,11 +471,18 @@ def make_round_step(
             return gf
         return None
 
+    def _scoped(mix):
+        """``mix`` under the gossip mix's named scope (HLO metadata only)."""
+        def scoped(tree):
+            with jax.named_scope(MIX_SCOPE):
+                return mix(tree)
+        return scoped
+
     def _comm(state, gf, ctx=None):
         """The communication step, channel-routed or plain."""
         if channel is None:
             mfn = (lambda tree: mix_fn(tree, ctx)) if scheduled else mix_fn
-            return algorithm.comm_update(state, mfn, gf, _reset_fn(gf))
+            return algorithm.comm_update(state, _scoped(mfn), gf, _reset_fn(gf))
         from ..compression.channels import ChannelSession, Transport  # lazy
 
         chan_state = getattr(state, "comp", None)
@@ -487,7 +499,7 @@ def make_round_step(
                       **(transport_hooks or {})),
         )
         new = algorithm.comm_update(
-            state, lambda tree: session.mix(tree, ctx), gf, _reset_fn(gf)
+            state, _scoped(lambda tree: session.mix(tree, ctx)), gf, _reset_fn(gf)
         )
         return dataclasses.replace(new, comp=session.final_state())
 

@@ -46,6 +46,10 @@ ScheduleOrFloat = Any
 
 __all__ = ["DSEState", "DSEMVR", "DSESGD", "tree_axpy", "tree_sub", "tree_add"]
 
+#: named scope of the update arithmetic (HLO metadata only), opened around
+#: each piece between the gradient and gossip calls, never around them
+UPDATE_SCOPE = "repro/update"
+
 
 def _sched(v: ScheduleOrFloat, t) -> jnp.ndarray:
     if callable(v):
@@ -158,29 +162,29 @@ class DSEMVR(DecentralizedAlgorithm):
         """One local MVR step.  ``grad_fn`` closes over ONE minibatch xi and is
         evaluated at both x_{t+1} and x_t (the paper's same-sample requirement).
         """
-        gamma = _sched(self.lr, state.step)
-        alpha = _sched(self.alpha, state.step + 1)
-        if self.use_fused:
-            # fused path: two bucketed kernel launches for the whole tree
-            # (x step + MVR direction), instead of 2 jnp passes per leaf
-            x_new = fused.tree_axpby(-gamma, state.v, 1.0, state.params)
-            g_new = grad_fn(x_new)
-            g_old = grad_fn(state.params)
-            v_new = fused.tree_mvr_update(g_new, state.v, g_old, alpha)
-            return dataclasses.replace(
-                state, params=x_new, v=v_new, step=state.step + 1
-            )
-        x_new = tree_axpy(-gamma, state.v, state.params)
+        with jax.named_scope(UPDATE_SCOPE):
+            gamma = _sched(self.lr, state.step)
+            alpha = _sched(self.alpha, state.step + 1)
+            if self.use_fused:
+                # fused path: two bucketed kernel launches for the whole tree
+                # (x step + MVR direction), instead of 2 jnp passes per leaf
+                x_new = fused.tree_axpby(-gamma, state.v, 1.0, state.params)
+            else:
+                x_new = tree_axpy(-gamma, state.v, state.params)
         g_new = grad_fn(x_new)
         g_old = grad_fn(state.params)
-        # v_{t+1} = g_{t+1} + (1 - alpha) (v_t - g_t)
-        v_new = jax.tree.map(
-            lambda gn, v, go: (gn + (1.0 - alpha) * (v.astype(gn.dtype) - go)).astype(v.dtype),
-            g_new,
-            state.v,
-            g_old,
-        )
-        return dataclasses.replace(state, params=x_new, v=v_new, step=state.step + 1)
+        with jax.named_scope(UPDATE_SCOPE):
+            if self.use_fused:
+                v_new = fused.tree_mvr_update(g_new, state.v, g_old, alpha)
+            else:
+                # v_{t+1} = g_{t+1} + (1 - alpha) (v_t - g_t)
+                v_new = jax.tree.map(
+                    lambda gn, v, go: (gn + (1.0 - alpha) * (v.astype(gn.dtype) - go)).astype(v.dtype),
+                    g_new,
+                    state.v,
+                    g_old,
+                )
+            return dataclasses.replace(state, params=x_new, v=v_new, step=state.step + 1)
 
     # -- communication round -------------------------------------------------
     def comm_update(
@@ -197,54 +201,59 @@ class DSEMVR(DecentralizedAlgorithm):
         v buffer is kept (used by the DSE-SGD subclass).
         """
         reset_grad_fn = reset_grad_fn if reset_grad_fn is not None else grad_fn
-        gamma = _sched(self.lr, state.step)
-        if self.use_fused:
-            # fused path: ONE dse_combine pass computes x_half, h and the SGT
-            # pre-mix message; the z refresh and the post-mix SPA subtraction
-            # are axpby launches (they cannot fuse across the gossip
-            # collective)
-            if self.fuse_tracking_buffers:
-                u, h_new = fused.tree_dse_combine(
-                    state.params, state.v, state.x_ref, state.z, gamma
-                )
-                y_new = mix_fn(u)
+        # the arithmetic is scoped in pieces around the two gossips, so no
+        # op of the mix (or of the reset gradient) lands in the update scope
+        with jax.named_scope(UPDATE_SCOPE):
+            gamma = _sched(self.lr, state.step)
+            if self.use_fused:
+                # fused path: ONE dse_combine pass computes x_half, h and the
+                # SGT pre-mix message; the z refresh and the post-mix SPA
+                # subtraction are axpby launches (they cannot fuse across the
+                # gossip collective)
+                if self.fuse_tracking_buffers:
+                    u, h_new = fused.tree_dse_combine(
+                        state.params, state.v, state.x_ref, state.z, gamma
+                    )
+                else:
+                    u, h_new = fused.tree_dse_combine_yh(
+                        state.params, state.v, state.x_ref, state.y, state.h_prev,
+                        gamma,
+                    )
+            else:
+                x_half = tree_axpy(-gamma, state.v, state.params)
+                h_new = tree_sub(_cast_like(state.x_ref, x_half), x_half)  # x_ref - x_half
+                h_new = _cast_like(h_new, state.v)
+                if self.fuse_tracking_buffers:
+                    u = tree_add(state.z, h_new)
+                else:
+                    u = tree_add(state.y, tree_sub(h_new, state.h_prev))
+        y_new = mix_fn(u)
+        with jax.named_scope(UPDATE_SCOPE):
+            if not self.fuse_tracking_buffers:
+                y_upd = dict(y=y_new, h_prev=h_new)
+            elif self.use_fused:
                 y_upd = dict(z=fused.tree_axpby(-1.0, h_new, 1.0, y_new))
             else:
-                u, h_new = fused.tree_dse_combine_yh(
-                    state.params, state.v, state.x_ref, state.y, state.h_prev,
-                    gamma,
-                )
-                y_new = mix_fn(u)
-                y_upd = dict(y=y_new, h_prev=h_new)
+                y_upd = dict(z=tree_sub(y_new, h_new))
             # SPA: x_{t+1} = mix(x_ref - y_{t+1})
-            x_new = mix_fn(
-                fused.tree_axpby(-1.0, y_new, 1.0, state.x_ref, like=state.params)
-            )
-        else:
-            x_half = tree_axpy(-gamma, state.v, state.params)
-            h_new = tree_sub(_cast_like(state.x_ref, x_half), x_half)  # x_ref - x_half
-            h_new = _cast_like(h_new, state.v)
-            if self.fuse_tracking_buffers:
-                y_new = mix_fn(tree_add(state.z, h_new))
-                z_new = tree_sub(y_new, h_new)
-                y_upd = dict(z=z_new)
+            if self.use_fused:
+                w = fused.tree_axpby(-1.0, y_new, 1.0, state.x_ref, like=state.params)
             else:
-                y_new = mix_fn(tree_add(state.y, tree_sub(h_new, state.h_prev)))
-                y_upd = dict(y=y_new, h_prev=h_new)
-            # SPA: x_{t+1} = mix(x_ref - y_{t+1})
-            x_new = mix_fn(tree_axpy(-1.0, _cast_like(y_new, state.x_ref), state.x_ref))
-        x_new = _cast_like(x_new, state.params)
-        v_new = state.v
-        if reset_grad_fn is not None:
-            v_new = _cast_like(reset_grad_fn(x_new), state.v)
-        return dataclasses.replace(
-            state,
-            params=x_new,
-            x_ref=jax.tree.map(jnp.copy, x_new),
-            v=v_new,
-            step=state.step + 1,
-            **y_upd,
-        )
+                w = tree_axpy(-1.0, _cast_like(y_new, state.x_ref), state.x_ref)
+        x_new = mix_fn(w)
+        with jax.named_scope(UPDATE_SCOPE):
+            x_new = _cast_like(x_new, state.params)
+        g = reset_grad_fn(x_new) if reset_grad_fn is not None else None
+        with jax.named_scope(UPDATE_SCOPE):
+            v_new = state.v if g is None else _cast_like(g, state.v)
+            return dataclasses.replace(
+                state,
+                params=x_new,
+                x_ref=jax.tree.map(jnp.copy, x_new),
+                v=v_new,
+                step=state.step + 1,
+                **y_upd,
+            )
 
     # legacy local_step / round_end shims live on the base class
     # (DecentralizedAlgorithm), where they warn once per class.
@@ -268,13 +277,16 @@ class DSESGD(DSEMVR):
         return super().init(params, full_grad_fn)
 
     def local_update(self, state: DSEState, grad_fn: GradFn) -> DSEState:
-        gamma = _sched(self.lr, state.step)
-        if self.use_fused:
-            x_new = fused.tree_axpby(-gamma, state.v, 1.0, state.params)
-        else:
-            x_new = tree_axpy(-gamma, state.v, state.params)
-        g_new = _cast_like(grad_fn(x_new), state.v)
-        return dataclasses.replace(state, params=x_new, v=g_new, step=state.step + 1)
+        with jax.named_scope(UPDATE_SCOPE):
+            gamma = _sched(self.lr, state.step)
+            if self.use_fused:
+                x_new = fused.tree_axpby(-gamma, state.v, 1.0, state.params)
+            else:
+                x_new = tree_axpy(-gamma, state.v, state.params)
+        g = grad_fn(x_new)
+        with jax.named_scope(UPDATE_SCOPE):
+            g_new = _cast_like(g, state.v)
+            return dataclasses.replace(state, params=x_new, v=g_new, step=state.step + 1)
 
     def comm_update(
         self,
@@ -286,6 +298,7 @@ class DSESGD(DSEMVR):
         state = DSEMVR.comm_update(self, state, mix_fn, None, None)
         rf = reset_grad_fn if reset_grad_fn is not None else grad_fn
         if rf is not None:  # v_{t+1} = g(x_{t+1}) — fresh minibatch
-            v_new = _cast_like(rf(state.params), state.v)
-            state = dataclasses.replace(state, v=v_new)
+            g = rf(state.params)
+            with jax.named_scope(UPDATE_SCOPE):
+                state = dataclasses.replace(state, v=_cast_like(g, state.v))
         return state

@@ -132,8 +132,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="bracket the training loop in jax.profiler.start_trace/"
                         "stop_trace writing a TensorBoard-loadable trace to DIR")
     p.add_argument("--telemetry-out", default=None, metavar="FILE",
-                   help="record fenced per-round spans, per-channel link-byte "
-                        "counters and loss gauges to a run-stamped JSONL file")
+                   help="record per-channel link-byte counters and loss "
+                        "gauges to a run-stamped JSONL file")
     p.add_argument("--trace-out", default=None, metavar="FILE",
                    help="elastic mode: stitch every process's spans into one "
                         "Chrome trace-event / Perfetto JSON file (per-round "
@@ -176,6 +176,29 @@ class TrainRun:
     round_s: List[float]
 
 
+def train_round(step, state, make_batch, shardings, r: int):
+    """Round ``r`` of :func:`train`'s loop: its batches on the host
+    (``make_batch(r)``), put on the devices (``shardings``), one call of the
+    donated round ``step``, and the wait for the loss.  Returns ``(state,
+    loss)``.
+
+    Under ``jax.profiler`` the round is a ``StepTraceAnnotation("round")``
+    holding one ``TraceAnnotation`` per host phase (``repro/host/batch``,
+    ``repro/host/put``, ``repro/host/step``: the dispatch,
+    ``repro/host/sync``: the wait), on the device trace's clock, so each
+    idle gap of the device falls in the host phase that left it idle."""
+    with jax.profiler.StepTraceAnnotation("round", step_num=r):
+        with jax.profiler.TraceAnnotation("repro/host/batch"):
+            batch = make_batch(r)
+        with jax.profiler.TraceAnnotation("repro/host/put"):
+            batch = jax.device_put(batch, shardings)
+        with jax.profiler.TraceAnnotation("repro/host/step"):
+            state, metrics = step(state, batch)
+        with jax.profiler.TraceAnnotation("repro/host/sync"):
+            loss = float(metrics["loss"])
+    return state, loss
+
+
 def train(cfg: ModelConfig, args: argparse.Namespace, mesh=None) -> TrainRun:
     """The single-process sharded training loop behind the CLI: build the
     job on ``mesh`` (default: :func:`make_mesh_for_devices`), shard the
@@ -204,22 +227,22 @@ def train(cfg: ModelConfig, args: argparse.Namespace, mesh=None) -> TrainRun:
 
     state = job.init_state(jax.random.key(args.seed))
 
-    def round_batches():
+    def host_batch():
         xs, ys = [], []
         for _ in range(rl):
             x, y = pipe.batch()
             xs.append(x.reshape(n, args.global_batch // n, args.seq_len))
             ys.append(y.reshape(n, args.global_batch // n, args.seq_len))
-        return jax.device_put(
-            {"tokens": np.stack(xs), "targets": np.stack(ys)},
-            job.batch_shardings,
-        )
+        return {"tokens": np.stack(xs), "targets": np.stack(ys)}
 
-    batches = round_batches()
+    first = jax.device_put(host_batch(), job.batch_shardings)
     t_c = time.perf_counter()
-    step = job.jit_step().lower(state, batches).compile()
+    step = job.jit_step().lower(state, first).compile()
     compile_s = time.perf_counter() - t_c
     print(f"[train] step compiled in {compile_s:.1f}s")
+
+    def make_batch(r):
+        return first if r == 0 else host_batch()   # round 0 runs the compile's batch
 
     ckpt = CheckpointManager(os.path.join(args.out, "ckpt")) if args.out and args.ckpt_every else None
 
@@ -231,7 +254,7 @@ def train(cfg: ModelConfig, args: argparse.Namespace, mesh=None) -> TrainRun:
 
         tel = Telemetry(config=vars(args))
         link = link_bytes_per_round(job.algorithm.comm, state.params)
-    from repro.telemetry.spans import profile_trace, span
+    from repro.telemetry.spans import profile_trace
 
     history = []
     round_s = []
@@ -239,12 +262,7 @@ def train(cfg: ModelConfig, args: argparse.Namespace, mesh=None) -> TrainRun:
     with profile_trace(args.profile):
         for r in range(args.steps):
             t_r = time.perf_counter()
-            if r:
-                batches = round_batches()
-            with span(tel, "round", step=r) as sp:
-                state, metrics = step(state, batches)
-                sp.fence((state, metrics))
-            loss = float(metrics["loss"])
+            state, loss = train_round(step, state, make_batch, job.batch_shardings, r)
             round_s.append(time.perf_counter() - t_r)
             if tel is not None:
                 tel.gauge("train_loss", loss, step=r + 1)

@@ -323,22 +323,26 @@ class Model:
         if kind in ("attn", "local", "moe", "shared_attn"):
             acfg = cfg.attn_cfg(kind)
             h = self._norm(x, bp["norm1"])
-            if mode == "decode":
-                y, new_attn_cache = attn_lib.attention_decode(acfg, bp["attn"], h, position, cache["attn"])
-            elif mode == "prefill":
-                y, new_attn_cache = attn_lib.attention_forward(acfg, bp["attn"], h, positions, return_cache=True)
-            else:
-                y, new_attn_cache = attn_lib.attention_forward(acfg, bp["attn"], h, positions), None
+            # named scopes (HLO metadata only) that the profiler's op names
+            # carry into the backward pass and remat as well
+            with jax.named_scope("repro/attn"):
+                if mode == "decode":
+                    y, new_attn_cache = attn_lib.attention_decode(acfg, bp["attn"], h, position, cache["attn"])
+                elif mode == "prefill":
+                    y, new_attn_cache = attn_lib.attention_forward(acfg, bp["attn"], h, positions, return_cache=True)
+                else:
+                    y, new_attn_cache = attn_lib.attention_forward(acfg, bp["attn"], h, positions), None
             if cfg.use_post_norm:
                 y = self._norm(y, bp["post_norm1"])
             x = x + y
             h = self._norm(x, bp["norm2"])
-            if kind == "moe":
-                y, moe_aux = mlp_lib.moe_forward(cfg.moe_cfg(), bp["ffn"], h, return_aux=(mode == "fwd"))
-                if moe_aux is not None:
-                    aux = aux + moe_aux
-            else:
-                y = mlp_lib.mlp_forward(cfg.mlp_cfg(), bp["ffn"], h)
+            with jax.named_scope("repro/mlp"):
+                if kind == "moe":
+                    y, moe_aux = mlp_lib.moe_forward(cfg.moe_cfg(), bp["ffn"], h, return_aux=(mode == "fwd"))
+                    if moe_aux is not None:
+                        aux = aux + moe_aux
+                else:
+                    y = mlp_lib.mlp_forward(cfg.mlp_cfg(), bp["ffn"], h)
             if cfg.use_post_norm:
                 y = self._norm(y, bp["post_norm2"])
             x = x + y
