@@ -54,7 +54,7 @@ __all__ = [
     "call", "tree_apply",
     "tree_mvr_update", "tree_axpby", "tree_add_sub",
     "tree_dse_combine", "tree_dse_combine_yh",
-    "launch_counts", "call_counts", "reset_counters",
+    "launch_counts", "call_counts", "count_call", "reset_counters",
 ]
 
 LANE = 128           # TPU lane width: flat buffers are padded to multiples
@@ -131,8 +131,14 @@ def reset_counters() -> None:
     _calls.clear()
 
 
-def _count(name: str, mode: str) -> None:
+def count_call(name: str) -> None:
+    """Count one trace-time event under ``name`` in :func:`call_counts`
+    (callers that choose between a kernel and a plain path record which)."""
     _calls[name] += 1
+
+
+def _count(name: str, mode: str) -> None:
+    count_call(name)
     if mode != "ref":
         _launches[name] += 1
 
@@ -203,7 +209,11 @@ class FusedOp:
     ref_fn:     pure-jnp oracle with the same calling convention as the
                 public entry (elementwise: ``ref_fn(*tensors, *scalars)``;
                 shaped: ``ref_fn(*tensors, **static)``).  It is the parity
-                target AND the backward pass of every dispatch.
+                target AND the backward pass of every dispatch, unless
+                ``kernel_vjp`` is set.
+    kernel_vjp: the shaped ``kernel_fn`` is differentiable itself (its
+                backward runs kernels too); ``call`` then uses it as it is
+                and the oracle's VJP only in ref mode.
     out_dtype_from: per output, the index of the input whose dtype the output
                 inherits (elementwise ops; kernel computes fp32, casts out).
     """
@@ -216,6 +226,7 @@ class FusedOp:
     n_outputs: int = 1
     n_scalars: int = 0
     out_dtype_from: Tuple[int, ...] = (0,)
+    kernel_vjp: bool = False
     tile: TilePolicy = TilePolicy()
     doc: str = ""
     _cache: Dict = dataclasses.field(
@@ -225,6 +236,8 @@ class FusedOp:
     def __post_init__(self):
         if self.expr is not None and self.kernel_fn is not None:
             raise ValueError(f"{self.name}: at most one of expr/kernel_fn")
+        if self.kernel_vjp and self.kernel_fn is None:
+            raise ValueError(f"{self.name}: kernel_vjp needs a kernel_fn")
         if self.expr is not None:
             if self.n_inputs <= 0:
                 raise ValueError(f"{self.name}: elementwise ops need n_inputs")
@@ -478,7 +491,8 @@ def call(name: str, *tensors, **static):
     scalar operands), so single arrays work too.  Ops registered without a
     kernel always run their oracle.
 
-    Always differentiable: the backward pass is ``jax.vjp`` of ``ref_fn``.
+    Always differentiable: the backward pass is ``jax.vjp`` of ``ref_fn``,
+    or the kernel's own for ops registered with ``kernel_vjp``.
     """
     op = get(name)
     if op.elementwise:
@@ -488,6 +502,9 @@ def call(name: str, *tensors, **static):
     mode = resolve_mode() if op.kernel_fn is not None else "ref"
     key = ("shaped", tuple(sorted(static.items())), mode)
     fn = op._cache.get(key)
+    if fn is None and op.kernel_vjp and mode != "ref":
+        fn = functools.partial(op.kernel_fn, interpret=(mode == "interpret"), **static)
+        op._cache[key] = fn
     if fn is None:
 
         def primal(*ts):

@@ -1,19 +1,28 @@
-"""Flash attention Pallas TPU kernel (forward).
+"""Flash attention on the TPU: JAX's splash-attention Pallas kernels.
 
-Design (TPU-native, not a CUDA port):
-  * grid = (batch, q_heads, Sq/BLOCK_Q, Skv/BLOCK_K).  TPU executes the grid
-    sequentially over the last dimension, so the online-softmax running state
-    (m, l, acc) lives in VMEM scratch and is carried across K steps — the
-    idiomatic TPU formulation (cf. the standard JAX TPU flash kernel), unlike
-    the CUDA version where one threadblock loops over K tiles.
-  * BlockSpecs tile Q/K/V into MXU-aligned (128, D) VMEM blocks; the kv-head
-    index for GQA is derived in the index_map (K/V tiles fetched per group).
-  * fully-masked K tiles (beyond the causal frontier / outside the sliding
-    window) skip their compute under ``pl.when``.
-  * fp32 accumulation; output written on the last K step.
+The kernel bodies (forward, dq, dkv) and the block-sparse mask tables come
+with JAX (``jax.experimental.pallas.ops.tpu.splash_attention``); this module
+launches them for the repo's (B, S, H, D) layout:
 
-VMEM footprint per program: q(128xD) + k,v(128xD each, bf16) + acc(128xD fp32)
-+ m,l vectors ~= 0.3 MB at D=128 — far under the ~16 MB/core budget.
+  * GQA runs the kernels' MQA form, one launch per (batch row, kv head) over
+    that head's group of q heads; the dkv kernel sums the group's gradients
+    in VMEM.
+  * the masks are block-sparse: key/value tiles wholly above the causal
+    diagonal or outside the window are skipped (neither fetched nor
+    computed), not masked; partial tiles mask by position in the kernel.
+  * scores, running max, sum and logsumexp are float32; q·kᵀ and the
+    backward's products take the inputs' dtype as MXU operands with float32
+    accumulation.  No (S, S) tensor is written to HBM: the custom VJP keeps
+    the output and the logsumexp, and dq, dk, dv come from the kernels.
+
+The launchers are splash's, cut to that one case (MQA, head-minor layouts,
+no segment ids, no sinks, masks computed from positions), and pass no
+``metadata``: Mosaic prints it inside the custom call's HLO instruction over
+several lines, which hides the call's ``op_name`` (its named scope) from a
+line-by-line reader of the compiled module.
+
+Logits are ``q·kᵀ``: the caller folds the 1/√D scale into q (the model does
+it in float32 inside RoPE, so q is rounded once, as before).
 """
 from __future__ import annotations
 
@@ -24,118 +33,248 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
+from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
+from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask_info as info_lib
 
-NEG_INF = -2.0e38
+#: q and kv tile of every kernel.  On a v5e at S = 2048, head_dim 128 a
+#: layer's forward+backward took 6.92 / 3.72 / 3.58 ms at 256 / 512 / 1024
+#: (PERF.md §6); 512 keeps the faster forward and skips more causal tiles.
+#: Shorter sequences take one tile.
+BLOCK = 512
+LANE = 128
+_SUBLANE = 8
+_HEAD_MINOR = dict(q_layout=sk.QKVLayout.HEAD_DIM_MINOR, k_layout=sk.QKVLayout.HEAD_DIM_MINOR,
+                   v_layout=sk.QKVLayout.HEAD_DIM_MINOR)
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
+
+def block_size(seq: int) -> int:
+    return min(BLOCK, seq)
 
 
-def _fa_kernel(
-    q_ref, k_ref, v_ref, o_ref,
-    acc_ref, m_ref, l_ref,
-    *, causal: bool, window: Optional[int], softcap: Optional[float],
-    block_q: int, block_k: int, num_k: int, scale: float,
-):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    q_start = qi * block_q
-    k_start = ki * block_k
+def tiles(seq: int) -> bool:
+    """Whether a sequence splits into whole lane-aligned tiles."""
+    block = block_size(seq)
+    return seq % block == 0 and block % LANE == 0
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    # tile-level relevance: any (q, k) pair in this tile unmasked?
-    relevant = jnp.bool_(True)
-    if causal:
-        relevant &= k_start <= q_start + block_q - 1
+def supported(seq: int, head_dim: int) -> bool:
+    """Whether the chip runs a (seq, head_dim) self-attention on the kernel:
+    whole tiles, and heads that fill the MXU's lanes."""
+    return tiles(seq) and head_dim % LANE == 0
+
+
+def _next(h, i, j, data_next, block_mask, mask_next, next_i=False):
+    return sk._next_nonzero(h, i, j, data_next, block_mask, mask_next, next_i=next_i)
+
+
+def _forward(info, mask_fn, q, k, v, *, block, softcap, interpret):
+    """Splash's forward kernel: out (G, S, D) and logsumexp (G, S)."""
+    g, s, d = q.shape
+    width = info.data_next.shape[-1]
+
+    def kv_map(h, i, j, *refs):
+        return _next(h, i, j, *refs)[0], 0
+
+    rows = lambda h, i, j, *_: (h, i, 0)  # noqa: E731
+    fixed = lambda *_: (0, 0)              # noqa: E731
+    q_seq = None
+    if info.q_sequence is not None:
+        q_seq = jax.lax.broadcast_in_dim(jnp.asarray(info.q_sequence), (s, LANE), (0,))
+    in_specs = [
+        pl.BlockSpec((None, block, d), rows),
+        pl.BlockSpec((block, d), kv_map),
+        pl.BlockSpec((block, d), kv_map),
+        None, None, None, None,              # segment ids, sinks, explicit mask blocks
+        None if q_seq is None else pl.BlockSpec((block, LANE), lambda h, i, j, *_: (i, 0)),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((block, LANE), jnp.float32),   # running max
+        jax.ShapeDtypeStruct((block, LANE), jnp.float32),   # running sum
+        jax.ShapeDtypeStruct((block, d), jnp.float32),      # accumulator
+        jax.ShapeDtypeStruct((g, s, d), q.dtype),
+        jax.ShapeDtypeStruct((g, s, LANE), jnp.float32),    # logsumexp
+    ]
+    out_specs = [pl.BlockSpec((block, LANE), fixed), pl.BlockSpec((block, LANE), fixed),
+                 pl.BlockSpec((block, d), fixed), pl.BlockSpec((None, block, d), rows),
+                 pl.BlockSpec((None, block, LANE), rows)]
+    kernel = functools.partial(
+        sk.flash_attention_kernel, mask_value=sk.DEFAULT_MASK_VALUE, grid_width=width,
+        bq=block, bkv=block, bkv_compute=block, head_dim_v=d,
+        attn_logits_soft_cap=softcap, mask_function=mask_fn, **_HEAD_MINOR,
+    )
+    *_, out, lse = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, in_specs=in_specs, out_specs=out_specs,
+            grid=(g, s // block, width)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        out_shape=out_shape, name="flash_attention_fwd", interpret=interpret,
+    )(info.data_next, info.block_mask, info.mask_next, q, k, v, None, None, None, None, q_seq)
+    return out, lse[..., 0]
+
+
+def _backward_dq(info, mask_fn, q, k, v, lse, do, di, *, block, softcap, interpret):
+    """Splash's dq kernel: dq (G, S, D)."""
+    g, s, d = q.shape
+    width = info.data_next.shape[-1]
+
+    def kv_map(h, i, j, *refs):
+        return _next(h, i, j, *refs)[0], 0
+
+    rows = lambda h, i, *_: (h, i, 0)    # noqa: E731
+    stat = lambda h, i, *_: (h, 0, i)    # noqa: E731
+    q_seq = None
+    if info.q_sequence is not None:
+        q_seq = jax.lax.broadcast_in_dim(jnp.asarray(info.q_sequence), (s, LANE), (0,))
+    in_specs = [
+        pl.BlockSpec((None, block, d), rows),
+        pl.BlockSpec((block, d), kv_map),
+        pl.BlockSpec((block, d), kv_map),
+        None, None, None,                              # segment ids, sinks
+        pl.BlockSpec((None, 1, block), stat),          # logsumexp
+        pl.BlockSpec((None, block, d), rows),          # do
+        pl.BlockSpec((None, 1, block), stat),          # di
+        None,                                          # explicit mask blocks
+        None if q_seq is None else pl.BlockSpec((block, LANE), lambda h, i, j, *_: (i, 0)),
+    ]
+    kernel = functools.partial(
+        sk._flash_attention_dq_kernel, mask_value=sk.DEFAULT_MASK_VALUE, grid_width=width,
+        bq=block, bkv=block, attn_logits_soft_cap=softcap, mask_function=mask_fn, **_HEAD_MINOR,
+    )
+    _, dq = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, in_specs=in_specs,
+            out_specs=[pl.BlockSpec((block, d), lambda *_: (0, 0)),
+                       pl.BlockSpec((None, block, d), rows)],
+            grid=(g, s // block, width)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        out_shape=[jax.ShapeDtypeStruct((block, d), jnp.float32),
+                   jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        name="flash_attention_dq", interpret=interpret,
+    )(info.data_next, info.block_mask, info.mask_next, q, k, v, None, None, None,
+      lse[:, None, :], do, di[:, None, :], None, q_seq)
+    return dq
+
+
+def _backward_dkv(info, mask_fn, q, k, v, lse, do, di, *, block, softcap, interpret):
+    """Splash's dkv kernel: dk and dv (S, D), summed over the G q heads."""
+    g, s, d = q.shape
+    width = info.data_next.shape[-2]
+
+    def q_rows(kv, h, i, *refs):
+        return h, _next(h, i, kv, *refs, next_i=True)[0], 0
+
+    def q_stat(kv, h, i, *refs):
+        return h, 0, _next(h, i, kv, *refs, next_i=True)[0]
+
+    def q_cols(kv, h, i, *refs):
+        return 0, _next(h, i, kv, *refs, next_i=True)[0]
+
+    kv_rows = lambda kv, *_: (kv, 0)   # noqa: E731
+    fixed = lambda *_: (0, 0)          # noqa: E731
+    q_seq = None
+    if info.q_sequence is not None:
+        q_seq = jax.lax.broadcast_in_dim(jnp.asarray(info.q_sequence), (_SUBLANE, s), (1,))
+    # logsumexp and di ride 8 sublanes, as the kernel reads them
+    stats = lambda x: jnp.broadcast_to(x[:, None, :], (g, _SUBLANE, s))  # noqa: E731
+    in_specs = [
+        pl.BlockSpec((None, block, d), q_rows),
+        pl.BlockSpec((block, d), kv_rows),
+        pl.BlockSpec((block, d), kv_rows),
+        None, None, None,                              # segment ids, sinks
+        pl.BlockSpec((None, _SUBLANE, block), q_stat),  # logsumexp
+        pl.BlockSpec((None, block, d), q_rows),         # do
+        pl.BlockSpec((None, _SUBLANE, block), q_stat),  # di
+        None,                                           # explicit mask blocks
+        None if q_seq is None else pl.BlockSpec((_SUBLANE, block), q_cols),
+    ]
+    out_shape = [
+        None,                                                # dq scratch (fused kernel only)
+        jax.ShapeDtypeStruct((block, d), jnp.float32),       # dk accumulator
+        jax.ShapeDtypeStruct((block, d), jnp.float32),       # dv accumulator
+        None,                                                # dq (fused kernel only)
+        jax.ShapeDtypeStruct(k.shape, k.dtype),
+        jax.ShapeDtypeStruct(v.shape, v.dtype),
+    ]
+    out_specs = [None, pl.BlockSpec((block, d), fixed), pl.BlockSpec((block, d), fixed), None,
+                 pl.BlockSpec((block, d), kv_rows), pl.BlockSpec((block, d), kv_rows)]
+    kernel = functools.partial(
+        sk._flash_attention_dkv_kernel, mask_value=sk.DEFAULT_MASK_VALUE, num_q_heads=g,
+        num_kv_heads=1, grid_width=width, bq=block, bkv_compute=block, is_mqa=True,
+        attn_logits_soft_cap=softcap, bkv=block, mask_function=mask_fn, **_HEAD_MINOR,
+    )
+    *_, dk, dv = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, in_specs=in_specs, out_specs=out_specs,
+            grid=(s // block, g, width)),
+        # the kv tiles accumulate over heads and q tiles: no axis is parallel
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        out_shape=out_shape, name="flash_attention_dkv", interpret=interpret,
+    )(info.data_next, info.block_mask, info.mask_next, q, k, v, None, None, None,
+      stats(lse), do, stats(di), None, q_seq)
+    return dk, dv
+
+
+@functools.lru_cache(maxsize=None)
+def _attend(seq: int, group: int, causal: bool, window: Optional[int],
+            softcap: Optional[float], interpret: bool):
+    """The differentiable (G, S, D) × (S, D) × (S, D) attention of one kv
+    head, with its mask tables built once (numpy constants)."""
     if window is not None:
-        relevant &= (q_start - (k_start + block_k - 1)) < window
+        mask = mask_lib.LocalMask((seq, seq), (window - 1, 0 if causal else None), 0)
+    elif causal:
+        mask = mask_lib.CausalMask((seq, seq))
+    else:
+        mask = mask_lib.FullMask((seq, seq))
+    heads = mask_lib.MultiHeadMask([mask] * group)
+    block = block_size(seq)
+    fwd_info, mask_fn = info_lib.process_mask(heads, (block, block))
+    dkv_info, _ = info_lib.process_mask_dkv(heads, (block, block))
+    # these masks come out as block tables (and, causal or windowed, a mask
+    # function of positions): never as explicit mask blocks
+    assert fwd_info.partial_mask_blocks is None and dkv_info.partial_mask_blocks is None
+    static = dict(block=block, softcap=softcap, interpret=interpret)
 
-    @pl.when(relevant)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (BQ, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (BK, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (BQ, BK)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = jnp.ones((block_q, block_k), jnp.bool_)
-        if causal:
-            mask &= qpos >= kpos
-        if window is not None:
-            mask &= (qpos - kpos) < window
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.maximum(m_prev, s.max(axis=-1))
-        corr = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_ref[...] = l_prev * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_cur
+    @jax.custom_vjp
+    def attend(q, k, v):
+        return _forward(fwd_info, mask_fn, q, k, v, **static)[0]
 
-    @pl.when(ki == num_k - 1)
-    def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+    def fwd(q, k, v):
+        out, lse = _forward(fwd_info, mask_fn, q, k, v, **static)
+        return out, (q, k, v, out, lse)
+
+    def bwd(res, do):
+        q, k, v, out, lse = res
+        di = jnp.einsum("hsd,hsd->hs", out.astype(jnp.float32), do.astype(jnp.float32))
+        dq = _backward_dq(fwd_info, mask_fn, q, k, v, lse, do, di, **static)
+        dk, dv = _backward_dkv(dkv_info, mask_fn, q, k, v, lse, do, di, **static)
+        return dq, dk, dv
+
+    attend.defvjp(fwd, bwd)
+    return attend
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "sliding_window", "softcap", "block_q", "block_k", "interpret"),
-)
-def flash_attention_fwd(
-    q: jnp.ndarray,          # (B, H, Sq, D)
-    k: jnp.ndarray,          # (B, K, Skv, D)
-    v: jnp.ndarray,
+def flash_attention(
+    q: jax.Array,            # (B, S, H, D), pre-scaled
+    k: jax.Array,            # (B, S, K, D)
+    v: jax.Array,            # (B, S, K, D)
     causal: bool = True,
     sliding_window: Optional[int] = None,
     softcap: Optional[float] = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
-) -> jnp.ndarray:
-    b, h, sq, d = q.shape
-    kh, skv = k.shape[1], k.shape[2]
-    assert h % kh == 0 and sq % block_q == 0 and skv % block_k == 0, (q.shape, k.shape)
-    q_per_kv = h // kh
-    num_k = skv // block_k
-    scale = 1.0 / (d ** 0.5)
-
-    kernel = functools.partial(
-        _fa_kernel,
-        causal=causal,
-        window=sliding_window,
-        softcap=softcap,
-        block_q=block_q,
-        block_k=block_k,
-        num_k=num_k,
-        scale=scale,
-    )
-    grid = (b, h, sq // block_q, num_k)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // q_per_kv, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // q_per_kv, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),   # acc
-            pltpu.VMEM((block_q,), jnp.float32),     # m (running max)
-            pltpu.VMEM((block_q,), jnp.float32),     # l (running denom)
-        ],
-        interpret=interpret,
-    )(q, k, v)
+) -> jax.Array:
+    b, s, h, d = q.shape
+    kh = k.shape[2]
+    if h % kh or k.shape[1] != s or not tiles(s):
+        raise ValueError(f"flash attention cannot take q {q.shape}, k {k.shape}")
+    g = h // kh
+    attend = _attend(s, g, causal, sliding_window, softcap, interpret)
+    qt = q.reshape(b, s, kh, g, d).transpose(0, 2, 3, 1, 4)     # (B, K, G, S, D)
+    out = jax.vmap(jax.vmap(attend))(qt, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)

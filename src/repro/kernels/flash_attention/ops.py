@@ -1,44 +1,27 @@
 """Registry entry + legacy wrapper for the flash-attention kernel.
 
 Canonical entry:
-``api.call("flash_attention", q, k, v, causal=..., sliding_window=..., softcap=...)``.
-The shaped launcher holds the layout adapter (model uses (B, S, H, D); kernel
-uses (B, H, S, D)); dispatch and the ref-backed custom VJP (backward
-recomputes attention with the jnp oracle — a flash backward kernel is tracked
-as a perf iteration) come from the fused-op API.
+``api.call("flash_attention", q, k, v, causal=..., sliding_window=..., softcap=...)``
+with q already scaled by 1/√D.  The kernel carries its own VJP (splash's dq
+and dkv kernels), so a differentiated call runs kernels both ways; the jnp
+oracle is the parity target and the path off the TPU.
 """
 from __future__ import annotations
 
 from .. import api
-from .kernel import flash_attention_fwd
+from .kernel import flash_attention as _flash_kernel_call
 from .ref import flash_attention_ref
 
 __all__ = ["flash_attention"]
-
-
-def _flash_kernel_call(
-    q, k, v, interpret=False, causal=True, sliding_window=None, softcap=None
-):
-    out = flash_attention_fwd(
-        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
-        causal=causal, sliding_window=sliding_window, softcap=softcap,
-        interpret=interpret,
-    )
-    return out.swapaxes(1, 2)
-
-
-def _flash_ref_call(q, k, v, causal=True, sliding_window=None, softcap=None):
-    return flash_attention_ref(
-        q, k, v, causal=causal, sliding_window=sliding_window, softcap=softcap
-    )
 
 
 api.register(
     api.FusedOp(
         name="flash_attention",
         kernel_fn=_flash_kernel_call,
-        ref_fn=_flash_ref_call,
+        ref_fn=flash_attention_ref,
         n_inputs=3,
+        kernel_vjp=True,
         doc="online-softmax attention, (B, S, H, D) layout, GQA/window/softcap",
     )
 )

@@ -1,5 +1,5 @@
 """Pure-jnp oracle for the flash-attention kernel (GQA + causal +
-sliding-window + score softcap)."""
+sliding-window + score softcap).  Logits are ``q·kᵀ``: q arrives scaled."""
 from __future__ import annotations
 
 from typing import Optional
@@ -23,7 +23,6 @@ def flash_attention_ref(
     assert h % kh == 0
     qg = q.reshape(b, sq, kh, h // kh, d)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg.astype(jnp.float32), k.astype(jnp.float32))
-    scores = scores / jnp.sqrt(jnp.float32(d))
     if softcap is not None:
         scores = softcap * jnp.tanh(scores / softcap)
     qpos = jnp.arange(sq)[:, None]

@@ -402,8 +402,11 @@ def make_train_job(
     def node_loss(params, batch):
         return model.loss(params, batch, dtype=jnp.bfloat16)
 
-    vgrad_full = jax.vmap(jax.grad(node_loss))
-    vloss = jax.vmap(node_loss)
+    # the node dim lies on the node axes: naming them lets a shard_map inside
+    # the model (the flash kernel) keep each node on its own devices
+    node_vmap = partial(jax.vmap, spmd_axis_name=tuple(node_axes) or None)
+    vgrad_full = node_vmap(jax.grad(node_loss))
+    vloss = node_vmap(node_loss)
 
     def vgrad(p, batch):
         """Per-node gradients, optionally microbatched (gradient accumulation
@@ -437,7 +440,7 @@ def make_train_job(
                 mb0 = jax.tree.map(lambda x: x[:, : x.shape[1] // grad_accum], b)
                 loss_cell.append(vloss(p, mb0).mean())
                 return vgrad(p, b)
-            losses, grads = jax.vmap(jax.value_and_grad(node_loss))(p, b)
+            losses, grads = node_vmap(jax.value_and_grad(node_loss))(p, b)
             loss_cell.append(losses.mean())
             return grads
 

@@ -4,8 +4,13 @@ Supports: grouped-query attention, causal or bidirectional masks, sliding
 windows (gemma2 local layers; windowed ring-buffer cache at decode),
 attention-score soft-capping, standard RoPE and M-RoPE.
 
-``impl='xla'`` is the jnp reference; ``impl='pallas'`` dispatches the
-flash-attention Pallas kernel (training/prefill only).
+Training/prefill dispatch (``attention_forward``): on the TPU a causal
+self-attention with arange positions (no M-RoPE), whole kernel tiles along
+the sequence and lane-dense heads runs the flash-attention kernel, forward
+and backward; everything else, and every run off the TPU, runs ``_sdpa``.
+``attn_impl='pallas'`` forces the kernel where it applies (interpret mode off
+the TPU); ``'blockwise'`` is the pure-XLA online-softmax scan.  Decode always
+runs ``_sdpa``.
 """
 from __future__ import annotations
 
@@ -14,8 +19,13 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from .common import Initializer, apply_mrope, apply_rope, logical_constraint, softcap
+from ..kernels import api as kernel_api
+from ..kernels.flash_attention import kernel as flash_kernel
+from .common import (
+    Initializer, activation_spec, apply_mrope, apply_rope, logical_constraint, shard_local,
+)
 
 __all__ = ["AttentionConfig", "init_attention", "attention_forward", "init_kv_cache", "attention_decode"]
 
@@ -35,7 +45,7 @@ class AttentionConfig:
     mrope_sections: Optional[Tuple[int, int, int]] = None  # M-RoPE if set
     use_bias: bool = False
     qk_norm: bool = False
-    attn_impl: str = "xla"                      # 'xla' | 'pallas'
+    attn_impl: str = "xla"                      # 'xla' | 'pallas' | 'blockwise'
 
     @property
     def q_per_kv(self) -> int:
@@ -60,7 +70,7 @@ def init_attention(cfg: AttentionConfig, ini: Initializer):
     return p
 
 
-def _project_qkv(cfg: AttentionConfig, params, x, positions):
+def _project_qkv(cfg: AttentionConfig, params, x, positions, q_scale: float = 1.0):
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(x.dtype))
@@ -77,7 +87,7 @@ def _project_qkv(cfg: AttentionConfig, params, x, positions):
         q = apply_mrope(q, positions, cfg.rope_theta, cfg.mrope_sections)
         k = apply_mrope(k, positions, cfg.rope_theta, cfg.mrope_sections)
     else:
-        q = apply_rope(q, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, scale=q_scale)
         k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
@@ -160,6 +170,36 @@ def _blockwise_sdpa(cfg: AttentionConfig, q, k, v, q_pos, kv_pos, block: int = 5
     return out.astype(q.dtype)
 
 
+def _takes_flash(cfg: AttentionConfig, seq: int) -> bool:
+    """Whether a (training / prefill) self-attention of ``seq`` positions
+    runs the flash kernel: see the module docstring."""
+    if not (cfg.causal and cfg.mrope_sections is None and flash_kernel.tiles(seq)):
+        return False
+    if cfg.attn_impl == "pallas":
+        return True
+    return (cfg.attn_impl == "xla" and kernel_api.resolve_mode() == "kernel"
+            and flash_kernel.supported(seq, cfg.head_dim))
+
+
+def _flash(cfg: AttentionConfig, q, k, v):
+    """The flash kernel on each device's own rows and heads: GSPMD cannot
+    partition a Pallas call and would gather q/k/v (a vmap over nodes with
+    ``spmd_axis_name`` keeps each node on its own devices)."""
+    mode = "kernel" if kernel_api.resolve_mode() == "kernel" else "interpret"
+
+    def call(q, k, v):
+        with kernel_api.dispatch_mode(mode):
+            return kernel_api.call(
+                "flash_attention", q, k, v,
+                causal=True, sliding_window=cfg.sliding_window, softcap=cfg.attn_softcap,
+            )
+
+    # q's heads split with k's, so each device keeps whole GQA groups
+    kv_spec = activation_spec(k.shape, "batch", None, "kv_heads", None)
+    q_spec = P(kv_spec[0], None, kv_spec[2], None)
+    return shard_local(call, q, k, v, specs=(q_spec, kv_spec, kv_spec), out_spec=q_spec)
+
+
 def attention_forward(
     cfg: AttentionConfig,
     params,
@@ -168,23 +208,21 @@ def attention_forward(
     return_cache: bool = False,
 ):
     """Full-sequence (training / prefill) attention.  x: (B, S, d)."""
-    q, k, v = _project_qkv(cfg, params, x, positions)
+    flash = _takes_flash(cfg, x.shape[1])
+    # the kernel's logits are q·kᵀ: the 1/√D scale rides RoPE's float32 math
+    q, k, v = _project_qkv(cfg, params, x, positions,
+                           q_scale=cfg.head_dim ** -0.5 if flash else 1.0)
     q = logical_constraint(q, "batch", "seq", "heads", None)
     k = logical_constraint(k, "batch", "seq", "kv_heads", None)
     v = logical_constraint(v, "batch", "seq", "kv_heads", None)
     pos1 = positions[0] if cfg.mrope_sections is not None else positions
-    if cfg.attn_impl == "pallas" and cfg.causal:
-        from ..kernels import api as kernel_api
-
-        out = kernel_api.call(
-            "flash_attention", q, k, v,
-            causal=True,
-            sliding_window=cfg.sliding_window,
-            softcap=cfg.attn_softcap,
-        )
+    if flash:
+        kernel_api.count_call("attn.flash")
+        out = _flash(cfg, q, k, v)
     elif cfg.attn_impl == "blockwise":
         out = _blockwise_sdpa(cfg, q, k, v, pos1, pos1)
     else:
+        kernel_api.count_call("attn.sdpa")
         out = _sdpa(cfg, q, k, v, pos1, pos1)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(out.dtype))
     y = logical_constraint(y, "batch", "seq", "embed")

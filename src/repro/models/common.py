@@ -23,7 +23,7 @@ PyTree = Any
 
 __all__ = [
     "LogicalAxes", "Initializer", "axis_rules", "logical_constraint",
-    "resolve_specs", "rms_norm", "layer_norm", "softcap",
+    "activation_spec", "shard_local", "resolve_specs", "rms_norm", "layer_norm", "softcap",
     "rope_frequencies", "apply_rope", "apply_mrope", "make_mrope_positions",
     "cross_entropy_loss", "Param",
 ]
@@ -119,6 +119,25 @@ def logical_constraint(x: jnp.ndarray, *names: Optional[str]) -> jnp.ndarray:
 
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     return jax.lax.with_sharding_constraint(x, spec)
+
+
+def shard_local(fn, *args: jnp.ndarray, specs: Sequence[P], out_spec: P):
+    """Run ``fn`` on each device's block of ``args``, laid out by ``specs``
+    (mesh axes of the active rules, e.g. from :func:`activation_spec`),
+    under ``shard_map``; a vmap's ``spmd_axis_name`` adds its axes.  For
+    calls GSPMD cannot partition (Pallas kernels), which it would otherwise
+    run on gathered operands.  Without rules or on one device: ``fn(*args)``."""
+    mesh = _RULES.mesh
+    if mesh is None or not _RULES.acts or mesh.size == 1:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(specs), out_specs=out_spec,
+                         check_vma=False)(*args)
+
+
+def activation_spec(shape: Sequence[int], *names: Optional[str]) -> P:
+    """The mesh axes the active rules give an activation of this shape and
+    these logical names (what :func:`logical_constraint` applies)."""
+    return _resolve_axes(names, shape, _RULES.acts)
 
 
 def force_replicated(x: jnp.ndarray) -> jnp.ndarray:
@@ -255,11 +274,16 @@ def _rotate(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0) -> jnp.ndarray:
-    """x: (..., S, n_heads, head_dim); positions: (..., S) int."""
+def apply_rope(
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0, scale: float = 1.0
+) -> jnp.ndarray:
+    """x: (..., S, n_heads, head_dim); positions: (..., S) int.  ``scale``
+    multiplies the rotation in float32, before the one cast back to x's dtype."""
     freqs = rope_frequencies(x.shape[-1], theta)  # (half,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]  # (.., S, 1, half)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     return _rotate(x, cos, sin)
 
 

@@ -14,7 +14,6 @@ from _hypothesis_compat import given, settings, st
 
 from repro.kernels import api
 from repro.kernels.flash_attention import flash_attention_ref
-from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.mvr_update import mvr_update_ref
 from repro.kernels.rms_norm import rms_norm_ref
 from repro.kernels.wkv_chunk import wkv_ref
@@ -27,8 +26,9 @@ def icall(name, *args, **static):
 
 
 def _qkv(key, b, s, h, kh, d, dtype):
+    """q scaled by 1/sqrt(d), as the kernel's callers hand it over."""
     ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (b, s, h, d)).astype(dtype)
+    q = (jax.random.normal(ks[0], (b, s, h, d)) * d ** -0.5).astype(dtype)
     k = jax.random.normal(ks[1], (b, s, kh, d)).astype(dtype)
     v = jax.random.normal(ks[2], (b, s, kh, d)).astype(dtype)
     return q, k, v
@@ -65,18 +65,17 @@ def test_flash_attention_sweep(b, s, h, kh, d, window, softcap, causal, dtype):
 
 
 def test_flash_attention_nonsquare_blocks():
-    """Uneven q/k block sizes still cover the sequence."""
-    q, k, v = _qkv(jax.random.key(0), 1, 256, 2, 2, 64, jnp.float32)
-    out = flash_attention_fwd(
-        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
-        causal=True, block_q=64, block_k=128, interpret=True,
-    ).swapaxes(1, 2)
+    """A sequence of several tiles (three of 512: skipped, diagonal and full
+    tiles, each q tile seeing a different number of kv tiles) is covered."""
+    q, k, v = _qkv(jax.random.key(0), 1, 1536, 2, 2, 64, jnp.float32)
+    out = icall("flash_attention", q, k, v, causal=True)
     ref = flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
 def test_flash_attention_grad_matches_ref():
-    """custom_vjp backward (oracle recompute) must match jnp autodiff."""
+    """The kernel's own backward (dq and dkv kernels) matches jnp autodiff
+    of the oracle."""
     q, k, v = _qkv(jax.random.key(1), 1, 128, 2, 2, 64, jnp.float32)
 
     def f_kernel(q, k, v):
@@ -89,6 +88,88 @@ def test_flash_attention_grad_matches_ref():
     g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "s,h,kh,d,window,softcap,dtype",
+    [
+        (256, 32, 4, 128, None, None, jnp.float32),     # Yi-9B heads, causal
+        (512, 32, 4, 128, None, None, jnp.float32),
+        (512, 32, 4, 128, None, None, jnp.bfloat16),
+        (1024, 8, 4, 256, 256, 50.0, jnp.float32),      # Gemma-2 local layer
+    ],
+)
+def test_flash_attention_matches_sdpa(s, h, kh, d, window, softcap, dtype):
+    """The kernel (q scaled by the caller) against the model's ``_sdpa``:
+    output and the gradients in q, k and v."""
+    from repro.models.attention import AttentionConfig, _sdpa
+
+    cfg = AttentionConfig(d_model=h * d, n_heads=h, n_kv_heads=kh, head_dim=d,
+                          sliding_window=window, attn_softcap=softcap)
+    ks = jax.random.split(jax.random.key(7), 4)
+    q, k, v, ct = (jax.random.normal(kk, shape).astype(dtype) for kk, shape in zip(
+        ks, [(1, s, h, d), (1, s, kh, d), (1, s, kh, d), (1, s, h, d)]))
+    pos = jnp.arange(s)[None]
+
+    def kernel(q, k, v):
+        qs = (q.astype(jnp.float32) * d ** -0.5).astype(q.dtype)
+        return icall("flash_attention", qs, k, v, causal=True,
+                     sliding_window=window, softcap=softcap)
+
+    out, vjp = jax.vjp(kernel, q, k, v)
+    want, vjp_want = jax.vjp(lambda q, k, v: _sdpa(cfg, q, k, v, pos, pos), q, k, v)
+    tol = TOL[dtype] if dtype == jnp.bfloat16 else dict(rtol=2e-4, atol=2e-5)
+    for got, ref in zip((out, *vjp(ct)), (want, *vjp_want(ct))):
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(ref, np.float32), **tol)
+
+
+def _attention_counts(cfg, seq, batch, mode):
+    """``api.call_counts`` of one traced gradient of ``cfg``'s loss."""
+    from repro.models import Model
+
+    model = Model(cfg)
+    api.reset_counters()
+    with api.dispatch_mode(mode):
+        jax.eval_shape(jax.grad(model.loss), model.param_shapes(),
+                       model.input_specs(seq, batch))
+    counts = api.call_counts()
+    return counts.get("attn.flash", 0), counts.get("attn.sdpa", 0)
+
+
+def _published(arch, **kw):
+    import dataclasses
+
+    from repro.configs import get_config
+
+    cfg = get_config(arch)
+    return dataclasses.replace(cfg, **{"n_layers": len(cfg.block_unit), **kw})
+
+
+@pytest.mark.parametrize("seq,batch", [(2048, 2), (512, 1), (2048, 1)])
+def test_attention_dispatch_benchmark_cells_take_the_kernel(seq, batch):
+    """Yi-9B at the benchmark's widths and each cell's per-node shapes
+    (s2k, s512, the ring's node) runs the kernel on the TPU, never _sdpa."""
+    cfg = _published("yi-9b", n_layers=2, vocab_size=8000)
+    flash, sdpa = _attention_counts(cfg, seq, batch, "kernel")
+    assert flash > 0 and sdpa == 0
+
+
+@pytest.mark.parametrize(
+    "arch,seq,mode,impl,takes",
+    [
+        ("yi-9b", 2048, "ref", "xla", False),           # off the TPU
+        ("yi-9b", 1000, "kernel", "xla", False),        # no whole tiles
+        ("yi-9b", 256, "ref", "pallas", True),          # forced: interpret mode
+        ("gemma2-2b", 1024, "kernel", "xla", True),     # window 4096, softcap 50
+        ("minitron-8b", 2048, "kernel", "xla", True),
+        ("zamba2-7b", 1024, "kernel", "xla", False),    # head_dim 112
+        ("hubert-xlarge", 1024, "kernel", "xla", False),  # bidirectional
+        ("qwen2-vl-2b", 1024, "kernel", "xla", False),  # M-RoPE positions
+    ],
+)
+def test_attention_dispatch_follows_what_it_sees(arch, seq, mode, impl, takes):
+    flash, sdpa = _attention_counts(_published(arch, attn_impl=impl), seq, 1, mode)
+    assert (flash > 0, sdpa > 0) == (takes, not takes)
 
 
 @settings(max_examples=8, deadline=None)

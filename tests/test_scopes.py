@@ -31,6 +31,7 @@ import trace_reduce  # noqa: E402
 SCOPES = ("repro/update", "repro/mix", "repro/attn", "repro/mlp")
 CASES = [(nodes, fused) for nodes in (1, 4) for fused in (False, True)]
 SEQ, ROWS, TAU = 16, 2, 3
+FLASH_SEQ = 128    # one whole kernel tile
 
 _OPCODE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (?:\([^=]*?\)|\S+) ([\w\-]+)\(")
 _FRAME = re.compile(r"stack_frame_id=(\d+)")
@@ -45,6 +46,7 @@ def build(out: str) -> None:
     text, and whether the step with its scopes nulled compiles to the same
     text (metadata aside) and returns bitwise the same state and loss."""
     import contextlib
+    import dataclasses
 
     import jax
     from jax._src.lib import xla_client
@@ -94,6 +96,20 @@ def build(out: str) -> None:
                 a.dtype == b.dtype and a.shape == b.shape and a.tobytes() == b.tobytes()
                 for a, b in zip(outs, plain_outs)),
         }
+    # the ring with the flash kernel forced (interpret mode on the CPU) and
+    # with _sdpa, at a sequence the kernel takes
+    devs = np.array(jax.devices()[:4]).reshape(4, 1)
+    mesh = jax.sharding.Mesh(devs, ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    for impl in ("xla", "pallas"):
+        jax.clear_caches()
+        job = make_train_job(dataclasses.replace(cfg, attn_impl=impl), mesh, tau=TAU, lr=1e-2,
+                             alpha=0.1, gossip="roll")
+        lowered = job.lower(FLASH_SEQ, ROWS * 4)
+        with open(os.path.join(out, f"flash.{impl}.compiled.hlo"), "w") as f:
+            f.write(lowered.compile().as_text())
+        with open(os.path.join(out, f"flash.{impl}.lowered.hlo"), "w") as f:
+            f.write(lowered.compiler_ir("hlo").get_hlo_module().to_string(opts))
     with open(os.path.join(out, "report.json"), "w") as f:
         json.dump(report, f)
 
@@ -153,6 +169,9 @@ def built(tmp_path_factory):
         report = json.load(f)
 
     def case(nodes, fused):
+        if nodes == "flash":   # the ring step with attn_impl=fused: "xla" or "pallas"
+            return tuple((out / f"flash.{fused}.{kind}.hlo").read_text()
+                         for kind in ("compiled", "lowered"))
         t = tag(nodes, fused)
         return ((out / f"{t}.compiled.hlo").read_text(), (out / f"{t}.lowered.hlo").read_text(),
                 report[t])
@@ -226,3 +245,34 @@ def test_scopes_are_metadata_only(built, nodes, fused):
     assert report["plain_scoped_ops"] == 0
     assert report["same_text"]
     assert report["leaves"] > 0 and report["same_outputs"]
+
+
+def test_flash_kernel_is_under_the_attention_scope(built):
+    """The flash kernel's forward, its remat and its backward (dq, dkv) all
+    sit under ``repro/attn``, and no op of the step carries two scopes."""
+    compiled, lowered = built("flash", "pallas")
+    for text in (compiled, lowered):
+        scopes = trace_reduce.scopes_from_hlo(text).values()
+        assert all(sum(s in op for s in SCOPES) <= 1 for op in scopes)
+    # parameters of reduction regions keep the region's own short name; they
+    # do no work
+    ops = opcodes(compiled)
+    kernel = [s for n, s in trace_reduce.scopes_from_hlo(compiled).items()
+              if "flash_attention_" in s and ops.get(n) != "parameter"]
+    assert kernel and all("repro/attn/" in s for s in kernel)
+    for part in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"):
+        assert any(part in s for s in kernel), part
+    assert any("rematted_computation/" in s and "flash_attention_fwd" in s for s in kernel)
+
+
+def test_flash_kernel_keeps_the_ring_collectives(built):
+    """On a ring of four devices the kernel runs on each device's own node:
+    the step's all-gathers, collective-permutes, all-reduces and all-to-alls
+    are those of the ``_sdpa`` step, one for one."""
+    def collectives(text):
+        ops = opcodes(text).values()
+        return sorted(op for op in ops if op.startswith(trace_reduce.COLLECTIVES))
+
+    flash, _ = built("flash", "pallas")
+    plain, _ = built("flash", "xla")
+    assert collectives(plain) and collectives(flash) == collectives(plain)

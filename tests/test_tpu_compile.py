@@ -13,6 +13,8 @@ worker imports this file.
 from __future__ import annotations
 
 import dataclasses
+import re
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -115,15 +117,85 @@ def test_top_k_runs_xla_path_on_mlp_leaf(one_chip, name):
 
 
 def test_flash_attention_compiles_at_gemma_widths(one_chip):
+    """Forward and backward kernels, window 4096 and softcap 50."""
     q = jax.ShapeDtypeStruct((1, 4096, 8, 256), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, 4096, 4, 256), jnp.bfloat16, sharding=one_chip)
-    compiled = _compile(
-        lambda q, k, v: api.call(
-            "flash_attention", q, k, v, causal=True, sliding_window=4096, softcap=50.0
-        ),
-        q, kv, kv,
-    )
-    assert "tpu_custom_call" in compiled.as_text()
+
+    def loss(q, k, v):
+        out = api.call("flash_attention", q, k, v, causal=True, sliding_window=4096, softcap=50.0)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv).as_text()
+    assert {"fwd", "dq", "dkv"} <= {k.rsplit("_", 1)[-1] for k in _kernels(text)}
+
+
+# one HLO instruction per line, its named scope in its metadata's op_name:
+# how the benchmark reads a compiled step (bench/trace_reduce.py)
+_INSTRUCTION = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+) = (?:\([^=]*?\)|\S+) ([\w\-]+)\(')
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+COLLECTIVES = ("all-gather", "collective-permute", "all-reduce", "all-to-all")
+
+
+def _kernels(text: str) -> dict:
+    """``{flash kernel: op_name}`` of the compiled module's TPU custom calls
+    named ``flash_attention_<fwd|dq|dkv>`` (op_name "" where the line has none)."""
+    out = {}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and 'custom_call_target="tpu_custom_call"' in line and "flash_attention_" in m.group(1):
+            scope = _OP_NAME.search(line)
+            kind = re.search(r"flash_attention_(fwd|dq|dkv)", m.group(1)).group(0)
+            out[f"{m.group(1)}:{kind}"] = scope.group(1) if scope else ""
+    return out
+
+
+def _collectives(text: str) -> Counter:
+    ops = (m.group(2) for m in map(_INSTRUCTION.match, text.splitlines()) if m)
+    return Counter(op for op in ops if op.startswith(COLLECTIVES))
+
+
+def _yi9b_bench():
+    """The benchmark's Yi-9B: published widths, 2 layers, 8000-word vocabulary."""
+    return dataclasses.replace(get_config("yi-9b"), n_layers=2, vocab_size=8000)
+
+
+def _step_text(mesh, mode, seq, global_batch, **job_kw):
+    job = make_train_job(_yi9b_bench(), mesh, algorithm="dse_mvr", tau=4, lr=1e-4,
+                         alpha=0.05, **job_kw)
+    jax.clear_caches()   # the step's trace caches the dispatch mode it saw
+    with api.dispatch_mode(mode):
+        return job.lower(seq, global_batch).compile().as_text()
+
+
+def test_s2k_step_runs_flash_attention_under_the_attention_scope(topo):
+    """The benchmark's s2k round step (one node, 2 x 2048 per step): every
+    flash kernel (forward, its remat, dq, dkv) carries ``repro/attn`` on its
+    own line, and no float32 matmul with two sequence-long dimensions is
+    left."""
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    text = _step_text(mesh, "kernel", 2048, 2)
+    kernels = _kernels(text)
+    assert {k.rsplit(":", 1)[1] for k in kernels} == {
+        "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"}
+    assert all("repro/attn/" in scope for scope in kernels.values()), kernels
+    assert any("rematted_computation/" in s and k.endswith("fwd") for k, s in kernels.items())
+    assert any("transpose(" in s and k.endswith("dkv") for k, s in kernels.items())
+    s_by_s = re.compile(r"= f32\[([\d,]*)\][^ ]* (?:dot|convolution)\(")
+    wide = [line for line in text.splitlines()
+            if (m := s_by_s.search(line)) and m.group(1).split(",").count("2048") >= 2]
+    assert wide == []
+
+
+def test_ring_step_keeps_its_collectives_with_flash_attention(topo):
+    """The ring cell (four nodes, one per chip, roll gossip): the kernel runs
+    on each chip's own node, so the step's collectives are the parent path's
+    (``_sdpa``) exactly; GSPMD would gather q/k/v around an unpartitioned
+    kernel call."""
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    plain = _step_text(mesh, "ref", 2048, 4, gossip="roll")
+    flash = _step_text(mesh, "kernel", 2048, 4, gossip="roll")
+    assert _kernels(flash) and not _kernels(plain)
+    assert _collectives(plain) and _collectives(flash) == _collectives(plain)
 
 
 def test_rms_norm_compiles_at_gemma_width(one_chip):
