@@ -23,6 +23,7 @@ Each line of stdout is one JSON object; the last sums them up.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -61,11 +62,11 @@ class Readings:
         m, tr = cell.model, cell.traffic
         self.job = harness.build_job(m, tr, devices)
         self.nodes = self.job.n_nodes
-        self.trainer = harness.Trainer(m, self.job, None)
-        self.refs = {"reference": Reference(m, tr, self.nodes, devices),
-                     "control": Reference(m, tr, self.nodes, devices, quant="fp8")}
+        self.trainer = harness.Trainer(cell, self.job, None)
+        ref = functools.partial(Reference, cell.arch, m, tr, self.nodes, devices)
+        self.refs = {"reference": ref(), "control": ref(quant="fp8")}
         if self.nodes > 1:
-            self.refs["no_exchange"] = Reference(m, tr, self.nodes, devices, mix=False)
+            self.refs["no_exchange"] = ref(mix=False)
 
     def seed(self, seed: int, *, controls: bool) -> dict:
         import jax

@@ -36,7 +36,7 @@ def fit(name: str, topo) -> None:
 
     import harness
     from reference import Reference
-    from weights import nest, param_shapes
+    from weights import nest
 
     cell = harness.load_cell(name)
     tr, m = cell.traffic, cell.model
@@ -46,11 +46,11 @@ def fit(name: str, topo) -> None:
     nodes = job.n_nodes
     _mem("round step", job.lower(tr["seq_len"], tr["node_batch"] * nodes).compile())
 
-    ref = Reference(m, tr, nodes, devices)
-    ctl = Reference(m, tr, nodes, devices, quant="fp8")
+    ref = Reference(cell.arch, m, tr, nodes, devices)
+    ctl = Reference(cell.arch, m, tr, nodes, devices, quant="fp8")
     node = NamedSharding(ref._node.mesh, P("n"))
     sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=node)  # noqa: E731
-    x = nest({p: sds((nodes,) + s) for p, (s, _, _) in param_shapes(m).items()})
+    x = nest({p: sds((nodes,) + s) for p, (s, _, _) in cell.arch.param_shapes(m).items()})
     st = {k: x for k in ("x", "x_ref", "v", "y", "h_prev")}
     b, S = tr["node_batch"], tr["seq_len"]
     bsh = NamedSharding(ref._node.mesh, P("n"))

@@ -1,6 +1,7 @@
 """What a run of a cell does, apart from its command line: find the cell's
-files by name, build the trainer's job on the chip's devices, make the state
-from the benchmark's weights, feed rounds, and take the readings that the
+files by name (its configuration's architecture module among them), build
+the trainer's job on the chip's devices, make the state from the
+benchmark's weights, feed rounds, and take the readings that the
 correctness check compares.
 
 The round loop is the benchmark's copy of the host work of
@@ -16,6 +17,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import types
 
 import jax
 import numpy as np
@@ -23,7 +25,7 @@ import numpy as np
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 
-__all__ = ["Cell", "load_cell", "metric_reader", "build_job", "Trainer"]
+__all__ = ["Cell", "load_cell", "load_module", "metric_reader", "build_job", "Trainer"]
 
 
 @dataclasses.dataclass
@@ -36,6 +38,7 @@ class Cell:
     traffic: dict         # bench/traffic/<traffic>.json
     checks: dict          # bench/checks/<workload>.json
     manifest: dict
+    arch: types.ModuleType  # the module that the configuration's "arch" names
 
     @property
     def model(self) -> dict:
@@ -61,34 +64,41 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     w = by_name[name]
     conf = {c["name"]: c for c in manifest["configs"]}[w["config"]]
     bench = root / "bench"
+    config = load_json(root / conf["file"])
     return Cell(
         name=name,
         chips=w["chips"],
-        config=load_json(root / conf["file"]),
+        config=config,
         traffic=load_json(bench / "traffic" / f"{w['traffic']}.json"),
         checks=load_json(bench / "checks" / f"{name}.json"),
         manifest=manifest,
+        arch=load_module(root / config["arch"], "bench_arch_"),
     )
+
+
+def load_module(path: pathlib.Path, prefix: str):
+    """The Python file at ``path``, loaded as a module of its own."""
+    spec = importlib.util.spec_from_file_location(prefix + path.stem, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str, root: pathlib.Path = ROOT):
     """``read(run) -> float | None`` of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(root / "bench" / "metrics" / f"{name}.py", "bench_metric_").read
+
+
+def _tuples(v):
+    return tuple(_tuples(x) for x in v) if isinstance(v, list) else v
 
 
 def model_config(m: dict):
-    """The trainer's ``ModelConfig`` for a configuration's ``model`` block."""
+    """The trainer's ``ModelConfig`` for a configuration's ``model`` block,
+    every list in it a tuple."""
     from repro.models import ModelConfig
 
-    kw = dict(m)
-    for k in ("block_unit", "mrope_sections", "vision_grid"):
-        if kw.get(k) is not None:
-            kw[k] = tuple(kw[k])
-    return ModelConfig(**kw)
+    return ModelConfig(**{k: _tuples(v) for k, v in m.items()})
 
 
 def build_job(m: dict, traffic: dict, devices):
@@ -120,18 +130,19 @@ class _SeededModel:
 class Trainer:
     """The job, its compiled round step and its state, driven round by round."""
 
-    def __init__(self, cell_model: dict, job, feed):
+    def __init__(self, cell: Cell, job, feed):
         from reference import leaf_norms
         from weights import make_params
 
         self.job, self.feed = job, feed
         self.nodes = job.n_nodes
-        self._params = jax.jit(lambda key: make_params(cell_model, key))
+        shapes = cell.arch.param_shapes(cell.model)
+        self._params = jax.jit(lambda key: make_params(shapes, key))
         want = jax.tree.map(lambda s: s.shape, job.model.param_shapes())
         got = jax.tree.map(lambda s: s.shape, jax.eval_shape(self._params, jax.random.key(0)))
         if want != got:
             raise SystemExit(f"benchmark weights do not match the trainer's layout:\n{got}\n{want}")
-        self._init_model = _SeededModel(job.model, lambda key: make_params(cell_model, key))
+        self._init_model = _SeededModel(job.model, lambda key: make_params(shapes, key))
         self._norms = jax.jit(leaf_norms)
         self._delta = jax.jit(lambda x, x0: leaf_norms(
             jax.tree.map(lambda a, b: a - b[None], x, x0)))

@@ -2,8 +2,9 @@
 
 Written from the published descriptions, importing nothing of the program:
 
-* the decoder (Yi: a Llama-architecture GQA decoder with RoPE and an
-  untied head), every matmul at ``precision=highest``;
+* the model's loss, from the cell's architecture module (``loss_fn``,
+  ``bench/models/``), every matmul through :func:`matmul` at
+  ``precision=highest``;
 * DSE-MVR (the paper's Alg. 1, with its separate ``y`` and ``h_prev``):
   tau-1 local steps ``x' = x - lr v``, ``v' = g(x') + (1-alpha)(v - g(x))``
   on one minibatch each, then the communication step
@@ -14,7 +15,7 @@ Written from the published descriptions, importing nothing of the program:
 
 Departures, all where the configuration states the program's behaviour:
 ``v``, ``y`` and ``h_prev`` start at zero (the trainer's default, where the
-paper starts ``v`` at a full gradient); RMSNorm's epsilon is 1e-6.
+paper starts ``v`` at a full gradient); the architecture module lists its own.
 
 ``quant="fp8"`` is the control: every matmul operand of the forward pass
 rounded to float8 e4m3 with a per-tensor scale (gradients pass straight
@@ -31,7 +32,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-__all__ = ["loss_fn", "Reference", "flat", "leaf_norms"]
+__all__ = ["Reference", "matmul", "flat", "leaf_norms"]
 
 F8_MAX = 448.0  # largest finite float8_e4m3fn
 
@@ -43,65 +44,15 @@ def _fp8(x):
     return x + lax.stop_gradient(q - x)
 
 
-def _mm(spec, a, b, quant):
-    if quant == "fp8":
-        a, b = _fp8(a), _fp8(b)
-    return jnp.einsum(spec, a, b, precision=lax.Precision.HIGHEST)
-
-
-def _rms(x, w, eps=1e-6):
-    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-
-
-def _rotate(x, ang):
-    """Rotate-half RoPE: x (b, S, H, hd), ang (b, S, hd/2)."""
-    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def _angles(m, b, S):
-    """Rotary angles (b, S, hd/2) of positions 0 .. S-1."""
-    half = m["head_dim"] // 2
-    inv = 1.0 / (m["rope_theta"] ** (np.arange(half, dtype=np.float64) / half))
-    ang = jnp.asarray(np.arange(S, dtype=np.float64)[:, None] * inv, jnp.float32)
-    return jnp.broadcast_to(ang[None], (b, S, half))
-
-
-def _layer(m, quant, x, lp, ang):
-    h, k, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    b, S, _ = x.shape
-    a = _rms(x, lp["norm1"])
-    q = _mm("bsd,dhk->bshk", a, lp["attn"]["wq"], quant)
-    kk = _mm("bsd,dhk->bshk", a, lp["attn"]["wk"], quant)
-    v = _mm("bsd,dhk->bshk", a, lp["attn"]["wv"], quant)
-    q, kk = _rotate(q, ang), _rotate(kk, ang)
-    kk = jnp.repeat(kk, h // k, axis=2)       # query head j reads kv head j // (h/k)
-    v = jnp.repeat(v, h // k, axis=2)
-    s = _mm("bshk,bthk->bhst", q, kk, quant) / np.sqrt(hd)
-    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
-    o = _mm("bhst,bthk->bshk", jax.nn.softmax(s, axis=-1), v, quant)
-    x = x + _mm("bshk,hkd->bsd", o, lp["attn"]["wo"], quant)
-    a = _rms(x, lp["norm2"])
-    g = _mm("bsd,df->bsf", a, lp["ffn"]["w_gate"], quant)
-    u = _mm("bsd,df->bsf", a, lp["ffn"]["w_up"], quant)
-    return x + _mm("bsf,fd->bsd", jax.nn.silu(g) * u, lp["ffn"]["w_down"], quant)
-
-
-def loss_fn(m: dict, params, batch, quant=None):
-    """Mean next-token cross-entropy of one node's batch."""
-    x = params["embed"][batch["tokens"]]
-    b, S, _ = x.shape
-    ang = _angles(m, b, S)
-    layer = jax.checkpoint(lambda x, lp: _layer(m, quant, x, lp, ang))
-    blocks = params["blocks"]["b0"]
-    for i in range(m["n_layers"]):
-        x = layer(x, jax.tree.map(lambda p: p[i], blocks))
-    x = _rms(x, params["final_norm"])
-    logits = _mm("bsd,dv->bsv", x, params["lm_head"], quant)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, batch["targets"][..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - gold)
+def matmul(quant=None):
+    """``mm(spec, a, b)``, which an architecture module's ``loss_fn`` takes
+    every matmul through: an einsum at ``precision=highest``, its operands
+    first rounded to float8 where ``quant="fp8"`` (the control)."""
+    def mm(spec, a, b):
+        if quant == "fp8":
+            a, b = _fp8(a), _fp8(b)
+        return jnp.einsum(spec, a, b, precision=lax.Precision.HIGHEST)
+    return mm
 
 
 def flat(tree, prefix="") -> dict:
@@ -124,14 +75,16 @@ def leaf_norms(tree) -> dict:
 
 class Reference:
     """DSE-MVR rounds over node-stacked float32 state, one node per device
-    of ``devices`` (or all nodes on the one device)."""
+    of ``devices`` (or all nodes on the one device), of the model that
+    architecture module ``arch`` writes out for configuration ``m``."""
 
-    def __init__(self, m: dict, traffic: dict, nodes: int, devices, *,
+    def __init__(self, arch, m: dict, traffic: dict, nodes: int, devices, *,
                  quant=None, mix=True):
         self.m, self.nodes = m, nodes
         lr, alpha = traffic["lr"], traffic["alpha"]
         mesh = jax.sharding.Mesh(np.array(devices[: max(1, min(nodes, len(devices)))]), ("n",))
         node = NamedSharding(mesh, P("n"))
+        mm = matmul(quant)
 
         def grad(x, batch):
             """Per-node (loss, gradient), one row of the batch at a time."""
@@ -140,7 +93,7 @@ class Reference:
                 tot_l, tot_g = 0.0, None
                 for r in range(rows):
                     row = jax.tree.map(lambda a: a[r:r + 1], bt)
-                    l_, g_ = jax.value_and_grad(lambda q: loss_fn(m, q, row, quant))(p)
+                    l_, g_ = jax.value_and_grad(lambda q: arch.loss_fn(m, q, row, mm))(p)
                     tot_l = tot_l + l_
                     tot_g = g_ if tot_g is None else jax.tree.map(jnp.add, tot_g, g_)
                 return tot_l / rows, jax.tree.map(lambda a: a / rows, tot_g)

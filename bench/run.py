@@ -18,8 +18,9 @@ of the machine it is started on:
    ``TRACE_S`` seconds with the profiler and reports the per-layer metrics
    of those rounds instead of the end-to-end ones;
 3. the check: the program's state is freed, and the float32 reference
-   (``bench/reference.py``) follows the same first rounds from the same
-   weights; ``bench/check.py`` compares.
+   (``bench/reference.py``, on the model of the configuration's
+   architecture module, ``bench/models/``) follows the same first rounds
+   from the same weights; ``bench/check.py`` compares.
 
 The last line of stdout is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, ``breakdown`` with ``--trace 1``,
@@ -169,7 +170,7 @@ def measure(cell: harness.Cell, seed: int, seconds: float, trace: bool, devices,
     marks.append(("job", time.perf_counter()))
     feed = Traffic.make(tr, m, nodes, seed)
     marks.append(("traffic", time.perf_counter()))
-    trainer = harness.Trainer(m, job, feed)
+    trainer = harness.Trainer(cell, job, feed)
     key = jax.random.key(seed)
     trainer.init(key)
     marks.append(("state", time.perf_counter()))
@@ -236,7 +237,7 @@ def measure(cell: harness.Cell, seed: int, seconds: float, trace: bool, devices,
     trainer.free()
 
     t_ref = time.perf_counter()
-    ref = Reference(m, tr, nodes, devices).run(
+    ref = Reference(cell.arch, m, tr, nodes, devices).run(
         trainer.x0(key), [feed.round(r) for r in range(tr["check_rounds"])])
     where: dict = {}
     values = check.numbers(prog, ref, where)
@@ -252,8 +253,10 @@ def measure(cell: harness.Cell, seed: int, seconds: float, trace: bool, devices,
     run = {
         "rounds": rounds_m, "window_s": window_m, "setup_s": setup_s, "chips": len(devices),
         "positions_per_round": flops.positions_per_round(tr, nodes),
-        "round_flops": flops.round_flops(m, tr, nodes), "peak": peak, "trace": tr_summary,
-        "program": program,
+        "round_flops": flops.round_flops(cell.arch, m, tr, nodes), "peak": peak,
+        "trace": tr_summary, "program": program,
+        # what a reader needs to count a kernel's work from the configuration
+        "model": m, "traffic": tr, "nodes": nodes, "arch": cell.arch,
     }
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}

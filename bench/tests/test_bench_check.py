@@ -119,16 +119,16 @@ def test_control_is_not_correct():
     cell = small_cell("yi9b.s2k.tau4")
     m, tr = cell.model, cell.traffic
     feed = Traffic.make(tr, m, 1, 2**31 + 3)
-    x0 = jax.jit(lambda k: control_weights(m, k))(jax.random.key(2**31 + 3))
+    x0 = jax.jit(lambda k: control_weights(cell.arch, m, k))(jax.random.key(2**31 + 3))
     rounds = [feed.round(r) for r in range(tr["check_rounds"])]
-    ref = Reference(m, tr, 1, jax.devices()[:1]).run(x0, rounds)
-    got = Reference(m, tr, 1, jax.devices()[:1], quant="fp8").run(x0, rounds)
+    ref = Reference(cell.arch, m, tr, 1, jax.devices()[:1]).run(x0, rounds)
+    got = Reference(cell.arch, m, tr, 1, jax.devices()[:1], quant="fp8").run(x0, rounds)
     ok, out = check.verdict(check.numbers(got, ref), cell.checks["limits"])
     assert not ok, out
     assert control.halve(rounds[0])["tokens"].shape == rounds[0]["tokens"].shape
 
 
-def control_weights(m, key):
+def control_weights(arch, m, key):
     from weights import make_params
 
-    return make_params(m, key)
+    return make_params(arch.param_shapes(m), key)

@@ -1,4 +1,5 @@
-"""Model FLOPs by hand, the peak table, and the refusal to measure off the chip."""
+"""Model FLOPs by hand (the ``llama`` architecture module, found through the
+cell's loader), the peak table, and the refusal to measure off the chip."""
 from __future__ import annotations
 
 import json
@@ -13,25 +14,22 @@ import flops
 import harness
 
 
-def _yi():
-    return harness.load_json(harness.ROOT / "bench/configs/yi-9b.json")["model"]
-
-
 def test_yi_9b_cut_by_hand():
-    m = _yi()
+    cell = harness.load_cell("yi9b.s2k.tau4")
+    llama, m = cell.arch, cell.model
     attn = 4096 * 32 * 128 * 2 + 4096 * 4 * 128 * 2          # wq, wo; wk, wv
     mlp = 3 * 4096 * 11008                                   # gate, up, down
     head = 4096 * 8000                                       # untied head
-    assert flops.matmul_params(m) == 2 * (attn + mlp) + head == 378_798_080
+    assert llama.matmul_params(m) == 2 * (attn + mlp) + head == 378_798_080
     causal = 6 * 2 * 32 * 128 * 2048
-    assert flops.pass_flops_per_position(m, 2048) == 6 * 378_798_080 + causal
+    assert llama.pass_flops_per_position(m, 2048) == 6 * 378_798_080 + causal
     s2k = harness.load_json(harness.ROOT / "bench/traffic/s2k.tau4.json")
     # tau 4: 3 local steps of two passes and one pass in the communication step
     assert flops.passes_per_round(s2k) == 7
     assert flops.positions_per_round(s2k, 1) == 4 * 2 * 2048
-    assert flops.round_flops(m, s2k, 1) == 7 * 2 * 2048 * (6 * 378_798_080 + causal)
+    assert flops.round_flops(llama, m, s2k, 1) == 7 * 2 * 2048 * (6 * 378_798_080 + causal)
     ring = harness.load_json(harness.ROOT / "bench/traffic/ring4.s2k.tau4.json")
-    assert flops.round_flops(m, ring, 4) == 4 * 7 * 1 * 2048 * (6 * 378_798_080 + causal)
+    assert flops.round_flops(llama, m, ring, 4) == 4 * 7 * 1 * 2048 * (6 * 378_798_080 + causal)
 
 
 def test_peaks_table():

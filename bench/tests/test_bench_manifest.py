@@ -98,6 +98,18 @@ def test_configs(manifest):
             assert conf["model"][key] == run and conf["published"][key] == published
 
 
+def test_every_configuration_names_its_architecture_module(manifest):
+    """``"arch"`` in each configuration file is a module under ``paths``
+    with the three functions the harness asks it for."""
+    for c in manifest["configs"]:
+        path = harness.load_json(ROOT / c["file"])["arch"]
+        assert PATH.match(path) and any(path.startswith(p + "/") for p in manifest["paths"])
+        assert (ROOT / path).is_file(), path
+        arch = harness.load_module(ROOT / path, "bench_arch_")
+        for fn in ("param_shapes", "loss_fn", "pass_flops_per_position"):
+            assert callable(getattr(arch, fn, None)), (path, fn)
+
+
 # the numbers that bench/check.py reads, each of which a cell may compare
 _one = {"loss": [1.0], "grad": {"a": [1.0]}, "delta": {"a": [1.0]}}
 NUMBERS = set(check.numbers(_one, _one))
